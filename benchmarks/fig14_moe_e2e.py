@@ -101,6 +101,9 @@ def _measure_device_probe():
     """Run the plan-vs-direct device exchange in a fresh fake-device
     process; returns the probe's measurement dict."""
     env = dict(os.environ)
+    # Fake host devices live on the CPU backend; pinned so the child never
+    # claims an accelerator the parent (or another process) owns.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{PROBE_PODS * PROBE_GPUS}")
     env["TF_CPP_MIN_LOG_LEVEL"] = "3"

@@ -20,6 +20,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (
     fig12_algbw,
     fig13_skew,
@@ -51,6 +53,7 @@ def main(argv=None) -> None:
         help="run only modules whose name contains SUBSTR "
              "(e.g. 'fig17' for the synthesis/overhead rows)")
     args = parser.parse_args(argv)
+    enable_compile_cache()
 
     mods = [m for m in MODULES if args.only in m.__name__]
     if not mods:

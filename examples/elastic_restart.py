@@ -22,6 +22,7 @@ give.
 """
 
 import os
+os.environ.setdefault("JAX_PLATFORMS", "cpu")  # the fake devices are CPUs
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse

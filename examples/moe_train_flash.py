@@ -11,6 +11,7 @@ changes).
 """
 
 import os
+os.environ.setdefault("JAX_PLATFORMS", "cpu")  # the fake devices are CPUs
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
@@ -53,11 +54,15 @@ def main():
         cfg, mesh, opts)
 
     model = build_model(cfg)
-    with jax.default_device(jax.devices()[0]):
-        params = model.init(jax.random.PRNGKey(0))
-    state = {"params": params, "opt": init_opt_state(params),
-             "step": jnp.zeros((), jnp.int32)}
-    state = jax.device_put(state, state_sh)
+
+    def init_state(key):
+        params = model.init(key)
+        return {"params": params, "opt": init_opt_state(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    # Each device materialises only its own shards of the state.
+    state = jax.jit(init_state, out_shardings=state_sh)(
+        jax.random.PRNGKey(0))
 
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                                   global_batch=args.batch), cfg)

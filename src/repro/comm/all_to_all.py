@@ -38,8 +38,6 @@ from __future__ import annotations
 from functools import partial
 from typing import Callable, Optional, Sequence, Tuple, Union
 
-from .. import jax_compat  # noqa: F401  (installs shims on older jax)
-
 import jax
 import jax.numpy as jnp
 from jax import lax

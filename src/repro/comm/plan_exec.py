@@ -49,15 +49,18 @@ special case of the same schedule):
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import AxisType, PartitionSpec as P
 
 from .all_to_all import _as_tuple, axis_sizes, register_all_to_all_impl
 
-__all__ = ["DeviceSchedule", "lower_plan", "is_lowered", "plan_all_to_all"]
+__all__ = ["DeviceSchedule", "lower_plan", "is_lowered", "slot_indices",
+           "plan_all_to_all"]
 
 _MEMO_ATTR = "_device_sched"
 _MEMO_CAP = 8  # serving loops see 1-2 pod counts per plan (Plan.compile's cap)
@@ -195,9 +198,62 @@ def is_lowered(plan_or_schedule, n_pods: Optional[int] = None) -> bool:
     return p in plan.__dict__.get(_MEMO_ATTR, {})
 
 
+def slot_indices(sched: DeviceSchedule, my_pod
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Pod ``my_pod``'s pack / unpack block indices, each int32 ``[S+1]``.
+
+    ``dst_idx`` is the pack gather order: slot 0 is the pod's own
+    (intra-pod) block, slot ``k+1`` the block it ships in stage ``k``; an
+    idle stage packs the local block again, which is never shipped (the
+    pod is absent from that stage's ppermute pairs).  ``src_idx`` is where
+    unpack scatters each received slot: the source pod's output block, or
+    the trash block ``P`` for a stage that delivers nothing here.
+    ``my_pod`` may be traced (``lax.axis_index``) or a plain int.
+    """
+    pod = jnp.asarray(my_pod, jnp.int32)
+
+    def column(table, idle):
+        if not sched.n_stages:
+            return pod[None]
+        col = jnp.take(jnp.asarray(table, jnp.int32), pod, axis=1)
+        return jnp.concatenate([pod[None], jnp.where(col < 0, idle, col)])
+
+    return (column(sched.dst_of, pod),
+            column(sched.src_of, jnp.int32(sched.n_pods)))
+
+
 def _default_interpret() -> bool:
-    # Pallas interpret mode everywhere but real TPUs (CPU CI, tests).
-    return jax.default_backend() != "tpu"
+    """Native Pallas on TPU, the Pallas interpreter on CPU, nothing else.
+
+    Any other backend is an error rather than a quiet switch to interpret
+    mode: a TPU run that lost its chip must fail, not keep going slowly.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"plan_all_to_all's Pallas kernels run natively on TPU or "
+        f"interpreted on CPU, not on backend {backend!r}; pass "
+        f"interpret= explicitly or use_kernel=False")
+
+
+def _manual_over_auto_axes(kernel):
+    """``kernel`` made manual over the mesh axes still automatic here.
+
+    A Mosaic kernel cannot be partitioned automatically, and the MoE island
+    is a partial-manual shard_map (``model`` stays automatic for the expert
+    FFN).  So the kernel gets a shard_map of its own over those axes, in
+    which every device runs it on the whole, replicated operands.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t != AxisType.Manual}
+    if not auto:
+        return kernel
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                         axis_names=auto, check_vma=False)
 
 
 @register_all_to_all_impl("plan")
@@ -245,19 +301,13 @@ def plan_all_to_all(x: jax.Array, slow_axis: str, fast_axes,
     s = sched.n_stages
 
     # Slot packing: bundle this device's send block for every stage into
-    # one destination-contiguous buffer (slot 0 = the intra-pod block).
-    # Idle stages (dst -1) pack the local block again; it is never shipped
-    # (the pod is absent from that stage's ppermute pairs).
-    dst_tab = jnp.asarray(sched.dst_of, jnp.int32)       # (S, P)
-    dst_idx = jnp.concatenate(
-        [my_pod[None].astype(jnp.int32),
-         jnp.take(dst_tab, my_pod, axis=1) if s else
-         jnp.zeros((0,), jnp.int32)])
-    dst_idx = jnp.where(dst_idx < 0, my_pod.astype(jnp.int32), dst_idx)
+    # one destination-contiguous buffer.
+    dst_idx, src_idx = slot_indices(sched, my_pod)
     if use_kernel:
         from ..kernels.a2a_pack.a2a_pack import a2a_pack, a2a_unpack
 
-        send = a2a_pack(x2, dst_idx, block_rows=block, interpret=interpret)
+        send = _manual_over_auto_axes(partial(
+            a2a_pack, block_rows=block, interpret=interpret))(x2, dst_idx)
     else:
         send = jnp.take(x2.reshape(p, block, d), dst_idx,
                         axis=0).reshape(-1, d)
@@ -282,16 +332,11 @@ def plan_all_to_all(x: jax.Array, slow_axis: str, fast_axes,
     # pod's output slot; non-receiving stages land in a trash block that
     # the final slice drops.  Coverage completion guarantees every real
     # output block is written exactly once.
-    src_tab = jnp.asarray(sched.src_of, jnp.int32)       # (S, P)
-    src_idx = jnp.concatenate(
-        [my_pod[None].astype(jnp.int32),
-         jnp.take(src_tab, my_pod, axis=1) if s else
-         jnp.zeros((0,), jnp.int32)])
-    src_idx = jnp.where(src_idx < 0, jnp.int32(p), src_idx)
     stack2 = stack.reshape((s + 1) * block, d)
     if use_kernel:
-        out2 = a2a_unpack(stack2, src_idx, n_out_blocks=p + 1,
-                          block_rows=block, interpret=interpret)
+        out2 = _manual_over_auto_axes(partial(
+            a2a_unpack, n_out_blocks=p + 1, block_rows=block,
+            interpret=interpret))(stack2, src_idx)
     else:
         out2 = jnp.zeros(((p + 1) * block, d), x.dtype)
         out2 = out2.reshape(p + 1, block, d).at[src_idx].set(

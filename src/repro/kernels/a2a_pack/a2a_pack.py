@@ -15,7 +15,9 @@ stores) exactly the block each slot needs: a data-dependent DMA schedule,
 no gather lowering in XLA.
 
 Block structure: ``block_rows`` rows move per index.  ``block_rows=1`` is
-the general row gather; the plan-driven A2A path uses pod-sized blocks
+the general row gather (interpret mode only: compiled for TPU, a block
+must be a multiple of 8 rows or the whole array, and anything else raises
+``ValueError``); the plan-driven A2A path uses pod-sized blocks
 (``block_rows = fast_size * capacity_rows``), and when ``block_rows`` is a
 multiple of 8 the grid tiles each block into (8, D) sublane tiles so the
 f32 (8, 128) register tile stays dense.  ``D`` need not be a multiple of
@@ -60,6 +62,15 @@ def _block_call(x, idx, *, n_out_rows: int, block_rows: int,
     xp = _pad_lanes(x)
     d = xp.shape[-1]
     m = idx.shape[0]
+    whole = block_rows == x.shape[0] == n_out_rows
+    if not interpret and block_rows % _SUBLANE and not whole:
+        # Mosaic only tiles (8k, 128j) blocks or whole arrays; fail here
+        # with the cause instead of deep inside lowering.
+        raise ValueError(
+            f"block_rows={block_rows} is neither a multiple of {_SUBLANE} "
+            f"nor the whole array ({x.shape[0]} in, {n_out_rows} out rows), "
+            f"which the TPU tiling refuses; pad blocks to a multiple of "
+            f"{_SUBLANE} rows or run with interpret=True")
     if block_rows % _SUBLANE == 0 and block_rows > _SUBLANE:
         t = block_rows // _SUBLANE
         grid = (m, t)

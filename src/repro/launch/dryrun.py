@@ -1,12 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")  # mute SPMD copy warnings
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any jax import -- jax locks the device
+The lines above MUST run before any jax import -- jax locks the device
 count at first init, and the dry-run needs 512 placeholder CPU devices to
-build the production meshes:
+build the production meshes (on the CPU backend, so the process never
+claims an attached accelerator):
 
     single pod : (16, 16)        ("data", "model")       256 chips
     multi-pod  : (2, 16, 16)     ("pod", "data", "model") 512 chips

@@ -12,22 +12,9 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from .. import jax_compat  # noqa: F401  (installs shims on older jax)
-
-try:  # AxisType landed after jax 0.4.x; plain meshes behave the same way
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_mesh", "dp_axes", "slow_axis"]
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
@@ -38,10 +25,11 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     if len(devices) < n:
         raise ValueError(
             f"need {n} devices for mesh {shape}, have {len(devices)}")
+    axis_types = (AxisType.Auto,) * len(axes)
     if len(devices) == n:
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=axis_types)
     arr = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(arr, axes, **_axis_type_kwargs(len(axes)))
+    return Mesh(arr, axes, axis_types=axis_types)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -19,10 +19,12 @@ from jax.sharding import Mesh
 
 from ..configs import ModelConfig, get_config, smoke_config
 from ..models import build_model, use_mesh_rules
+from .compile_cache import enable_compile_cache
 from .shardings import cache_shardings, param_shardings
 from .train import make_dist_context, make_rules
 
-__all__ = ["make_serve_step", "make_prefill_step", "serve_state_shapes"]
+__all__ = ["make_serve_step", "make_prefill_step", "generate",
+           "serve_state_shapes"]
 
 
 def serve_state_shapes(cfg: ModelConfig, mesh: Optional[Mesh],
@@ -63,18 +65,44 @@ def make_serve_step(cfg: ModelConfig, mesh: Optional[Mesh],
 
 
 def make_prefill_step(cfg: ModelConfig, mesh: Optional[Mesh],
-                      a2a_impl: Optional[str] = None, plan=None):
-    """jit'd (params, batch) -> (logits, cache | aux)."""
+                      a2a_impl: Optional[str] = None, plan=None,
+                      cache_len: Optional[int] = None):
+    """jit'd (params, batch) -> (logits, cache | aux).
+
+    ``cache_len`` sizes the decode cache for prompt + generation budget
+    (decoder-only LMs; default = prompt length).
+    """
     model = build_model(cfg)
     dist = make_dist_context(cfg, mesh, a2a_impl, plan=plan) \
         if mesh is not None else None
     rules = make_rules(cfg, mesh) if mesh is not None else None
+    extra = {} if cache_len is None else {"cache_len": cache_len}
 
     def prefill_step(params, batch):
         with use_mesh_rules(rules):
-            return model.prefill(params, batch, dist)
+            return model.prefill(params, batch, dist, **extra)
 
     return jax.jit(prefill_step)
+
+
+def generate(prefill, step, params, tokens, gen_len: int):
+    """Greedy decoding of a batch: prefill ``tokens`` [B, S], then
+    ``gen_len - 1`` decode steps from position S.
+
+    ``prefill`` / ``step`` are the callables ``make_prefill_step`` (with
+    ``cache_len >= S + gen_len``) and ``make_serve_step`` return.  Returns
+    ``(logits, tokens)``: ``gen_len`` device arrays each, [B, V] and [B].
+    """
+    logits, cache = prefill(params, {"tokens": tokens})
+    toks = jnp.argmax(logits, -1)
+    all_logits, out = [logits], [toks]
+    start = tokens.shape[1]
+    for t in range(start, start + gen_len - 1):
+        logits, cache = step(params, cache, toks, jnp.int32(t))
+        toks = jnp.argmax(logits, -1)
+        all_logits.append(logits)
+        out.append(toks)
+    return all_logits, out
 
 
 # -- CPU-scale batched-serving demo ------------------------------------------
@@ -152,30 +180,26 @@ def main():
                          "plan-serving daemon (repro.serving) instead of "
                          "the inline PlanCache path")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.a2a:
         import dataclasses as _dc
         cfg = _dc.replace(cfg, a2a_impl=args.a2a)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # Under jit the parameters are generated on the device in their own
+    # dtype, with no eager per-leaf f32 temporaries.
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, cfg.vocab,
                            (args.batch, args.prompt_len)).astype(np.int32)
     total = args.prompt_len + args.gen_len
-
-    from ..models.transformer import lm_prefill
-    t0 = time.perf_counter()
-    logits, cache = lm_prefill(cfg, params, jnp.asarray(prompts),
-                               cache_len=total)
-    toks = jnp.argmax(logits, -1)
+    prefill = make_prefill_step(cfg, mesh=None, cache_len=total)
     step = make_serve_step(cfg, mesh=None)
-    out = [toks]
-    for t in range(args.prompt_len, total - 1):
-        logits, cache = step(params, cache, toks, jnp.int32(t))
-        toks = jnp.argmax(logits, -1)
-        out.append(toks)
-    jax.block_until_ready(toks)
+    t0 = time.perf_counter()
+    _, out = generate(prefill, step, params, jnp.asarray(prompts),
+                      args.gen_len)
+    jax.block_until_ready(out)
     dt = time.perf_counter() - t0
     gen = np.stack([np.asarray(t) for t in out], axis=1)
     tput = args.batch * gen.shape[1] / dt

@@ -24,6 +24,7 @@ from ..models import DistContext, MeshRules, build_model, choose_ep_axes, \
     use_mesh_rules
 from ..optim import AdamWConfig, adamw_update, cosine_schedule, \
     init_opt_state
+from .compile_cache import enable_compile_cache
 from .mesh import dp_axes, slow_axis
 from .shardings import batch_shardings, state_shardings
 
@@ -210,6 +211,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     opts = TrainOptions(peak_lr=args.lr, warmup_steps=max(args.steps // 10, 1),

@@ -23,7 +23,8 @@ class Model:
     cfg: ModelConfig
     init: Callable[[jax.Array], Any]
     loss: Callable[..., Any]          # (params, batch, dist) -> (loss, metrics)
-    prefill: Callable[..., Any]       # (params, batch, dist) -> (logits, cache)
+    prefill: Callable[..., Any]       # (params, batch, dist[, cache_len])
+                                      #   -> (logits, cache)
     init_cache: Callable[..., Any]    # (batch, seq_len) -> cache
     decode_step: Callable[..., Any]   # (params, cache, tokens, pos, dist)
 
@@ -48,8 +49,9 @@ def build_model(cfg: ModelConfig) -> Model:
         init=lambda key: transformer.init_lm(key, cfg),
         loss=lambda params, batch, dist=None: transformer.lm_loss(
             cfg, params, batch, dist),
-        prefill=lambda params, batch, dist=None: transformer.lm_prefill(
-            cfg, params, batch["tokens"], batch, dist),
+        prefill=lambda params, batch, dist=None, cache_len=None:
+            transformer.lm_prefill(cfg, params, batch["tokens"], batch, dist,
+                                   cache_len=cache_len),
         init_cache=lambda batch, seq_len: transformer.init_decode_cache(
             cfg, batch, seq_len),
         decode_step=lambda params, cache, tokens, pos, dist=None:

@@ -19,6 +19,8 @@ SRC = os.path.join(REPO, "src")
 def run_subprocess(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     """Run python code in a fresh process with n fake CPU devices."""
     env = dict(os.environ)
+    # The fake devices are CPU devices: never let the child claim a TPU.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env["TF_CPP_MIN_LOG_LEVEL"] = "3"

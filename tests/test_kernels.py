@@ -167,3 +167,18 @@ def test_a2a_pack_unpack_round_trip_property(n_blocks, block_rows, d, seed):
     back = a2a_unpack_op(packed, perm, n_out_blocks=n_blocks,
                          block_rows=block_rows, interpret=True)
     assert jnp.array_equal(back, x)
+
+
+@pytest.mark.parametrize("op", ["pack", "unpack"])
+@pytest.mark.parametrize("block_rows", [1, 4, 12])
+def test_a2a_block_rows_off_tpu_tiling_raise(op, block_rows):
+    """Compiled for TPU (interpret=False), a block that is neither a
+    multiple of 8 rows nor the whole array is refused up front with the
+    cause, not deep inside Mosaic lowering."""
+    from repro.kernels.a2a_pack.a2a_pack import a2a_pack, a2a_unpack
+
+    x = jnp.zeros((3 * block_rows, 128), jnp.float32)
+    idx = jnp.arange(3, dtype=jnp.int32)
+    fn = a2a_pack if op == "pack" else a2a_unpack
+    with pytest.raises(ValueError, match="multiple of 8"):
+        fn(x, idx, block_rows=block_rows, interpret=False)

@@ -79,3 +79,41 @@ assert err < 1e-4, err
 print("POD_EP_OK", err)
 """)
     assert "POD_EP_OK" in out
+
+
+def test_served_plan_exchange_matches_direct(subproc):
+    """The served model with impl="plan" (Pallas pack/unpack, interpreted)
+    on a mesh whose ``model`` axis stays automatic inside the MoE island:
+    prefill and decode logits bit-identical to impl="direct"."""
+    out = subproc("""
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import smoke_config
+from repro.core.schedulers import get_scheduler
+from repro.core.traffic import ClusterSpec, moe_workload
+from repro.launch.mesh import make_mesh
+from repro.launch.serve import make_prefill_step, make_serve_step
+from repro.launch.shardings import param_shardings
+from repro.models import build_model
+
+cfg = smoke_config("megatron-moe-32e", scan_layers=True)
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+model = build_model(cfg)
+key = jax.random.PRNGKey(0)
+params = jax.jit(model.init, out_shardings=param_shardings(
+    cfg, mesh, jax.eval_shape(model.init, key)))(key)
+plan = get_scheduler("flash").synthesize(
+    moe_workload(ClusterSpec(2, 2), 64, 2, seed=0))
+tokens = np.random.default_rng(0).integers(0, cfg.vocab, (4, 16))
+logits = {}
+for impl in ("plan", "direct"):
+    p = plan if impl == "plan" else None
+    lg, cache = make_prefill_step(cfg, mesh, impl, plan=p, cache_len=18)(
+        params, {"tokens": jnp.asarray(tokens, jnp.int32)})
+    step = make_serve_step(cfg, mesh, impl, plan=p)
+    lg2, _ = step(params, cache, jnp.argmax(lg, -1), jnp.int32(16))
+    logits[impl] = [np.asarray(lg), np.asarray(lg2)]
+for a, b in zip(logits["plan"], logits["direct"]):
+    assert np.array_equal(a, b)
+print("SERVED_PLAN_OK")
+""")
+    assert "SERVED_PLAN_OK" in out

@@ -140,3 +140,36 @@ def test_capacity_aware_dedup():
     sched = lower_plan(get_scheduler("flash_ca").synthesize(w))
     pairs, distinct = _coverage(sched)
     assert len(pairs) == len(distinct) == 12
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", False), ("cpu", True),
+                                          ("gpu", None)])
+def test_default_interpret_is_explicit_per_backend(monkeypatch, backend,
+                                                   want):
+    """Native kernels on TPU, the interpreter on CPU, and an error on any
+    other backend -- never a quiet fall back to interpret mode."""
+    from repro.comm import plan_exec
+
+    monkeypatch.setattr(plan_exec.jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            plan_exec._default_interpret()
+    else:
+        assert plan_exec._default_interpret() is want
+
+
+@pytest.mark.parametrize("n_servers", [2, 4])
+def test_slot_indices_match_stage_tables(n_servers):
+    """Pack order = own block then each stage's target (own block when
+    idle); unpack target = each stage's source (trash block P when idle)."""
+    from repro.comm.plan_exec import slot_indices
+
+    sched = lower_plan(get_scheduler("flash").synthesize(
+        moe_workload(ClusterSpec(n_servers, 2), 256, 2, seed=3)))
+    p = sched.n_pods
+    for pod in range(p):
+        dst, src = (np.asarray(a) for a in slot_indices(sched, pod))
+        assert dst.tolist() == [pod] + [
+            d if d >= 0 else pod for d in (row[pod] for row in sched.dst_of)]
+        assert src.tolist() == [pod] + [
+            s if s >= 0 else p for s in (row[pod] for row in sched.src_of)]

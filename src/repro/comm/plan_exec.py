@@ -57,6 +57,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import AxisType, PartitionSpec as P
 
+from .. import scopes
 from .all_to_all import _as_tuple, axis_sizes, register_all_to_all_impl
 
 __all__ = ["DeviceSchedule", "lower_plan", "is_lowered", "slot_indices",
@@ -303,42 +304,46 @@ def plan_all_to_all(x: jax.Array, slow_axis: str, fast_axes,
     # Slot packing: bundle this device's send block for every stage into
     # one destination-contiguous buffer.
     dst_idx, src_idx = slot_indices(sched, my_pod)
-    if use_kernel:
-        from ..kernels.a2a_pack.a2a_pack import a2a_pack, a2a_unpack
+    with jax.named_scope(scopes.EXCHANGE_PACK):
+        if use_kernel:
+            from ..kernels.a2a_pack.a2a_pack import a2a_pack, a2a_unpack
 
-        send = _manual_over_auto_axes(partial(
-            a2a_pack, block_rows=block, interpret=interpret))(x2, dst_idx)
-    else:
-        send = jnp.take(x2.reshape(p, block, d), dst_idx,
-                        axis=0).reshape(-1, d)
-    buf = send.reshape(s + 1, i, *rest) if rest else \
-        send.reshape(s + 1, i)
+            send = _manual_over_auto_axes(partial(
+                a2a_pack, block_rows=block, interpret=interpret))(x2,
+                                                                  dst_idx)
+        else:
+            send = jnp.take(x2.reshape(p, block, d), dst_idx,
+                            axis=0).reshape(-1, d)
+        buf = send.reshape(s + 1, i, *rest) if rest else \
+            send.reshape(s + 1, i)
 
-    # Load balance: ONE intra-pod all_to_all rail-aligns every stage block
-    # (the plan's LoadBalancePhase; redistribute is then a no-op).
-    if fast:
-        buf = lax.all_to_all(buf, fast, split_axis=1, concat_axis=1,
-                             tiled=True)
+    with jax.named_scope(scopes.EXCHANGE_STAGE):
+        # Load balance: ONE intra-pod all_to_all rail-aligns every stage
+        # block (the plan's LoadBalancePhase; redistribute is a no-op).
+        if fast:
+            buf = lax.all_to_all(buf, fast, split_axis=1, concat_axis=1,
+                                 tiled=True)
 
-    # Merged transfers: one ppermute per lowered stage, stage-sized
-    # contiguous buffers, static (src, dst) pairs baked from the plan.
-    recv = [buf[0]]
-    for k in range(s):
-        recv.append(lax.ppermute(buf[k + 1], slow_axis,
-                                 list(sched.pairs[k])))
-    stack = jnp.stack(recv)                              # (S+1, i, *rest)
+        # Merged transfers: one ppermute per lowered stage, stage-sized
+        # contiguous buffers, static (src, dst) pairs baked from the plan.
+        recv = [buf[0]]
+        for k in range(s):
+            recv.append(lax.ppermute(buf[k + 1], slow_axis,
+                                     list(sched.pairs[k])))
+        stack = jnp.stack(recv)                          # (S+1, i, *rest)
 
     # Slot unpacking: scatter each received stage block to its source
     # pod's output slot; non-receiving stages land in a trash block that
     # the final slice drops.  Coverage completion guarantees every real
     # output block is written exactly once.
-    stack2 = stack.reshape((s + 1) * block, d)
-    if use_kernel:
-        out2 = _manual_over_auto_axes(partial(
-            a2a_unpack, n_out_blocks=p + 1, block_rows=block,
-            interpret=interpret))(stack2, src_idx)
-    else:
-        out2 = jnp.zeros(((p + 1) * block, d), x.dtype)
-        out2 = out2.reshape(p + 1, block, d).at[src_idx].set(
-            stack2.reshape(s + 1, block, d)).reshape(-1, d)
-    return out2[: p * block].reshape(n, *rest)
+    with jax.named_scope(scopes.EXCHANGE_UNPACK):
+        stack2 = stack.reshape((s + 1) * block, d)
+        if use_kernel:
+            out2 = _manual_over_auto_axes(partial(
+                a2a_unpack, n_out_blocks=p + 1, block_rows=block,
+                interpret=interpret))(stack2, src_idx)
+        else:
+            out2 = jnp.zeros(((p + 1) * block, d), x.dtype)
+            out2 = out2.reshape(p + 1, block, d).at[src_idx].set(
+                stack2.reshape(s + 1, block, d)).reshape(-1, d)
+        return out2[: p * block].reshape(n, *rest)

@@ -50,7 +50,7 @@ def _copy_kernel(idx_ref, x_ref, o_ref):
 
 
 def _block_call(x, idx, *, n_out_rows: int, block_rows: int,
-                in_map, out_map, interpret: bool):
+                in_map, out_map, interpret: bool, name: str):
     """Shared pallas_call builder for pack (gather) and unpack (scatter).
 
     ``in_map`` / ``out_map`` build the BlockSpec index maps from the
@@ -93,6 +93,7 @@ def _block_call(x, idx, *, n_out_rows: int, block_rows: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=interpret,
+        name=name,
     )(idx.astype(jnp.int32), xp)
     return out[:, :d_in] if d != d_in else out
 
@@ -133,7 +134,8 @@ def a2a_pack(
             return lambda i, idx_ref: (i, 0)
 
     return _block_call(x, idx, n_out_rows=m * r, block_rows=r,
-                       in_map=in_map, out_map=out_map, interpret=interpret)
+                       in_map=in_map, out_map=out_map, interpret=interpret,
+                       name="a2a_pack")
 
 
 def a2a_unpack(
@@ -180,4 +182,5 @@ def a2a_unpack(
             return lambda i, idx_ref: (idx_ref[i], 0)
 
     return _block_call(x, idx, n_out_rows=n_out, block_rows=r,
-                       in_map=in_map, out_map=out_map, interpret=interpret)
+                       in_map=in_map, out_map=out_map, interpret=interpret,
+                       name="a2a_unpack")

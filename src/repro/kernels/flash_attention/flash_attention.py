@@ -137,5 +137,6 @@ def flash_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, s, d)

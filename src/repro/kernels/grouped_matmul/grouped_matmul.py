@@ -94,4 +94,5 @@ def grouped_matmul(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="grouped_matmul",
     )(counts.astype(jnp.int32), x, w)

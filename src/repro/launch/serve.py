@@ -15,8 +15,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
+from .. import scopes
 from ..configs import ModelConfig, get_config, smoke_config
 from ..models import build_model, use_mesh_rules
 from .compile_cache import enable_compile_cache
@@ -40,6 +42,22 @@ def serve_state_shapes(cfg: ModelConfig, mesh: Optional[Mesh],
             cache_shape, cache_shardings(cfg, mesh, cache_shape))
 
 
+class _Spanned:
+    """A jitted step whose every call is a host span around its dispatch
+    (a no-op unless a profiler runs).  Every attribute -- ``lower``,
+    ``trace``, ... -- is the jitted function's."""
+
+    def __init__(self, jitted, span: str):
+        self._jitted, self._span = jitted, span
+
+    def __call__(self, *args, **kwargs):
+        with TraceAnnotation(self._span):
+            return self._jitted(*args, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
 def make_serve_step(cfg: ModelConfig, mesh: Optional[Mesh],
                     a2a_impl: Optional[str] = None, plan=None):
     """jit'd (params, cache, tokens [B], pos) -> (logits [B, V], cache).
@@ -60,8 +78,9 @@ def make_serve_step(cfg: ModelConfig, mesh: Optional[Mesh],
             return model.decode_step(params, cache, tokens, pos, dist)
 
     if mesh is None:
-        return jax.jit(serve_step)
-    return jax.jit(serve_step, donate_argnums=(1,))
+        return _Spanned(jax.jit(serve_step), scopes.SERVE_DECODE_STEP)
+    return _Spanned(jax.jit(serve_step, donate_argnums=(1,)),
+                    scopes.SERVE_DECODE_STEP)
 
 
 def make_prefill_step(cfg: ModelConfig, mesh: Optional[Mesh],
@@ -82,7 +101,7 @@ def make_prefill_step(cfg: ModelConfig, mesh: Optional[Mesh],
         with use_mesh_rules(rules):
             return model.prefill(params, batch, dist, **extra)
 
-    return jax.jit(prefill_step)
+    return _Spanned(jax.jit(prefill_step), scopes.SERVE_PREFILL)
 
 
 def generate(prefill, step, params, tokens, gen_len: int):
@@ -94,12 +113,14 @@ def generate(prefill, step, params, tokens, gen_len: int):
     ``(logits, tokens)``: ``gen_len`` device arrays each, [B, V] and [B].
     """
     logits, cache = prefill(params, {"tokens": tokens})
-    toks = jnp.argmax(logits, -1)
+    with TraceAnnotation(scopes.SERVE_ARGMAX):
+        toks = jnp.argmax(logits, -1)
     all_logits, out = [logits], [toks]
     start = tokens.shape[1]
     for t in range(start, start + gen_len - 1):
         logits, cache = step(params, cache, toks, jnp.int32(t))
-        toks = jnp.argmax(logits, -1)
+        with TraceAnnotation(scopes.SERVE_ARGMAX):
+            toks = jnp.argmax(logits, -1)
         all_logits.append(logits)
         out.append(toks)
     return all_logits, out

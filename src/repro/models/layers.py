@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .sharding import logical_constraint
+from .. import scopes
 from ..configs.registry import ModelConfig
 
 NEG_INF = -1e30
@@ -125,6 +126,7 @@ def init_attention(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     return p
 
 
+@scopes.scoped(scopes.ATTENTION_PROJ)
 def _project_qkv(cfg: ModelConfig, p: dict, x: jax.Array,
                  positions: jax.Array, rope: bool = True):
     b, s, _ = x.shape
@@ -260,26 +262,29 @@ def attention_apply(
     b, s, d = x.shape
     h, kv = cfg.n_heads, cfg.n_kv_heads
     q, k, v = _project_qkv(cfg, p, x, positions)
-    kr = _repeat_kv(k, h // kv)
-    vr = _repeat_kv(v, h // kv)
-    eff_window = window if (window is not None) else None
-    if s > chunked_threshold:
-        out = mha_chunked(q, kr, vr, q_offset=0, window=eff_window,
-                          use_window=use_window, causal=causal)
-    else:
-        mask = _band_mask(s, s, 0, eff_window, causal)
-        if eff_window is not None:
-            full = _band_mask(s, s, 0, None, causal)
-            mask = jnp.where(jnp.asarray(use_window), mask, full)
-        out = mha_einsum(q, kr, vr, mask)
-    out = out.reshape(b, s, h * cfg.resolved_head_dim)
-    out = out @ p["wo"].astype(out.dtype)
-    out = logical_constraint(out, "batch", "act_seq", "model_dim")
+    with jax.named_scope(scopes.ATTENTION_CORE):
+        kr = _repeat_kv(k, h // kv)
+        vr = _repeat_kv(v, h // kv)
+        eff_window = window if (window is not None) else None
+        if s > chunked_threshold:
+            out = mha_chunked(q, kr, vr, q_offset=0, window=eff_window,
+                              use_window=use_window, causal=causal)
+        else:
+            mask = _band_mask(s, s, 0, eff_window, causal)
+            if eff_window is not None:
+                full = _band_mask(s, s, 0, None, causal)
+                mask = jnp.where(jnp.asarray(use_window), mask, full)
+            out = mha_einsum(q, kr, vr, mask)
+    with jax.named_scope(scopes.ATTENTION_PROJ):
+        out = out.reshape(b, s, h * cfg.resolved_head_dim)
+        out = out @ p["wo"].astype(out.dtype)
+        out = logical_constraint(out, "batch", "act_seq", "model_dim")
     if not return_kv:
         return out
     return out, (k, v)
 
 
+@scopes.scoped(scopes.ATTENTION_KV_CACHE)
 def assemble_kv_cache(k: jax.Array, v: jax.Array, window: Optional[int],
                       cache_len: int) -> Tuple[jax.Array, jax.Array]:
     """Place prefill keys/values [B, S, K, Dh] into a decode cache of
@@ -327,25 +332,29 @@ def attention_decode(
     # so ring-buffer slot order is irrelevant (softmax is permutation
     # invariant over kv slots).
     slot = pos if window is None else pos % s_phys
-    cache_k = jax.lax.dynamic_update_index_in_dim(
-        cache_k, k_new[:, 0], slot, axis=1)
-    cache_v = jax.lax.dynamic_update_index_in_dim(
-        cache_v, v_new[:, 0], slot, axis=1)
+    with jax.named_scope(scopes.ATTENTION_KV_CACHE):
+        cache_k = jax.lax.dynamic_update_index_in_dim(
+            cache_k, k_new[:, 0], slot, axis=1)
+        cache_v = jax.lax.dynamic_update_index_in_dim(
+            cache_v, v_new[:, 0], slot, axis=1)
     # Grouped-query einsum against the raw cache: materializing the GQA
     # repeat would force an all-gather of the dh-sharded cache.
     g = h // kv
-    q5 = q.reshape(b, 1, kv, g, dh)
-    scores = jnp.einsum("bqkgd,bskd->bqkgs", q5, cache_k).astype(
-        jnp.float32) / np.sqrt(dh)
-    # Valid slots: the min(pos + 1, s_phys) most recent positions.  For the
-    # windowed ring buffer (s_phys == window) every written slot is in-window
-    # by construction.
-    idx = jnp.arange(s_phys)
-    valid = idx < jnp.minimum(pos + 1, s_phys)
-    scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bqkgs,bskd->bqkgd", probs, cache_v)
-    out = out.reshape(b, 1, h * dh) @ p["wo"].astype(x.dtype)
+    with jax.named_scope(scopes.ATTENTION_CORE):
+        q5 = q.reshape(b, 1, kv, g, dh)
+        scores = jnp.einsum("bqkgd,bskd->bqkgs", q5, cache_k).astype(
+            jnp.float32) / np.sqrt(dh)
+        # Valid slots: the min(pos + 1, s_phys) most recent positions.  For
+        # the windowed ring buffer (s_phys == window) every written slot is
+        # in-window by construction.
+        idx = jnp.arange(s_phys)
+        valid = idx < jnp.minimum(pos + 1, s_phys)
+        scores = jnp.where(valid[None, None, None, None, :], scores,
+                           NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bqkgs,bskd->bqkgd", probs, cache_v)
+    with jax.named_scope(scopes.ATTENTION_PROJ):
+        out = out.reshape(b, 1, h * dh) @ p["wo"].astype(x.dtype)
     return out, cache_k, cache_v
 
 

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..comm.all_to_all import resolve_all_to_all
+from .. import scopes
 from ..configs.registry import ModelConfig
 from .dist import DistContext
 from .layers import dense_init
@@ -61,6 +62,7 @@ def _capacity(cfg: ModelConfig, n_tokens: int, n_experts: int) -> int:
     return max(8, -(-c // 8) * 8) if n_tokens < 1024 else -(-c // 128) * 128
 
 
+@scopes.scoped(scopes.MOE_ROUTE)
 def _route(cfg: ModelConfig, router_w, x_flat):
     """Top-k routing. Returns (gates [T,k], eids [T,k], aux_loss scalar)."""
     e = cfg.moe.num_experts
@@ -75,6 +77,7 @@ def _route(cfg: ModelConfig, router_w, x_flat):
     return gates.astype(x_flat.dtype), eids, aux
 
 
+@scopes.scoped(scopes.MOE_DISPATCH)
 def _dispatch(x_flat, eids, capacity: int, n_experts: int):
     """Sort-based dispatch into a [E * C, d] buffer.
 
@@ -100,6 +103,7 @@ def _dispatch(x_flat, eids, capacity: int, n_experts: int):
     return buf, slot, keep
 
 
+@scopes.scoped(scopes.MOE_COMBINE)
 def _combine(y_buf, slot, keep, gates, t: int, k: int):
     """Gather expert outputs back to (token, choice), weight, and sum."""
     y = y_buf[slot] * keep[:, None]
@@ -107,6 +111,7 @@ def _combine(y_buf, slot, keep, gates, t: int, k: int):
     return (y * gates[..., None]).sum(axis=1)
 
 
+@scopes.scoped(scopes.MOE_EXPERT_FFN)
 def _expert_ffn(cfg: ModelConfig, w_gate, w_up, w_down, tokens):
     """tokens: [E_loc, C_tot, d] -> [E_loc, C_tot, d] (grouped SwiGLU).
 
@@ -140,7 +145,7 @@ def _moe_island(cfg: ModelConfig, dist: DistContext, x, router_w,
     buf = buf.reshape(g, e_loc * cap, d)
 
     if g > 1:
-        a2a = resolve_all_to_all(dist)
+        a2a = scopes.scoped(scopes.MOE_EXCHANGE)(resolve_all_to_all(dist))
         recv = a2a(buf)                                     # [G, E_loc*C, d]
     else:
         recv = buf
@@ -197,6 +202,7 @@ def _moe_pod_ep(cfg: ModelConfig, dist: DistContext, p: dict, x: jax.Array):
     cap = _capacity(cfg, t_loc, e)
     k = cfg.moe.top_k
 
+    @scopes.scoped(scopes.MOE_EXCHANGE)
     def _exchange(buf):
         """a2a over the EP axis: FLASH rotations on the slow (DCN) axis,
         flat all_to_all on a fast (ICI) axis; optionally int8-quantized.
@@ -295,9 +301,11 @@ def _moe_pod_ep(cfg: ModelConfig, dist: DistContext, p: dict, x: jax.Array):
         wd_sub = ep_char + "efd"
     tok_sub = ("pgecd" if len(dp) > 1 else "pecd")
     out_sub = tok_sub.replace("d", "f")
-    h = jax.nn.silu(jnp.einsum(f"{tok_sub},{w_sub}->{out_sub}", tg, wg)) \
-        * jnp.einsum(f"{tok_sub},{w_sub}->{out_sub}", tg, wu)
-    y = jnp.einsum(f"{out_sub},{wd_sub}->{tok_sub}", h, wd)
+    with jax.named_scope(scopes.MOE_EXPERT_FFN):
+        h = jax.nn.silu(jnp.einsum(f"{tok_sub},{w_sub}->{out_sub}", tg,
+                                   wg)) \
+            * jnp.einsum(f"{tok_sub},{w_sub}->{out_sub}", tg, wu)
+        y = jnp.einsum(f"{out_sub},{wd_sub}->{tok_sub}", h, wd)
     y = cstr(y, P(*dp_spec_full, None, None, None))
     y = y.reshape(n_shards, e_loc, p_pods * cap, d)
 

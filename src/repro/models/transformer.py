@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import scopes
 from ..configs.registry import ModelConfig
 from .dist import DistContext
 from .layers import (
@@ -96,6 +97,7 @@ def _window_args(cfg: ModelConfig, full_flag) -> Tuple[Optional[int], Any]:
     return cfg.swa_window, jnp.logical_not(full_flag)
 
 
+@scopes.scoped(scopes.TRANSFORMER_BLOCK)
 def _block_train(cfg: ModelConfig, p: dict, x, *, positions, dist,
                  kind: str, full_flag, emit_cache: bool = False,
                  cache_len: int = 0):
@@ -190,6 +192,7 @@ def _embed_tokens(cfg: ModelConfig, params, tokens, extras) -> jax.Array:
     return logical_constraint(x, "batch", "act_seq", "model_dim")
 
 
+@scopes.scoped(scopes.LM_HEAD)
 def _lm_logits(cfg: ModelConfig, params, x) -> jax.Array:
     x = norm_apply(cfg, params["final_norm"], x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -326,6 +329,7 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq_len: int) -> Any:
         for i in range(cfg.n_layers)]
 
 
+@scopes.scoped(scopes.TRANSFORMER_BLOCK)
 def _block_decode(cfg: ModelConfig, p: dict, cache: dict, x, pos, *,
                   kind: str, full_flag, dist) -> Tuple[jax.Array, dict]:
     if kind == "m":
@@ -378,8 +382,11 @@ def lm_decode_step(cfg: ModelConfig, params, cache, tokens, pos,
                 full_flag=flag_l, dist=dist)
             return xx, new_cache_l
 
-        x, new_cache = jax.lax.scan(body, x,
-                                    (params["blocks"], cache, flags))
+        # XLA slices each layer's cache out of the stack and writes it
+        # back around the body: that is cache traffic too.
+        with jax.named_scope(scopes.ATTENTION_KV_CACHE):
+            x, new_cache = jax.lax.scan(body, x,
+                                        (params["blocks"], cache, flags))
     else:
         new_cache = []
         for i, (p_l, cache_l) in enumerate(zip(params["blocks"], cache)):
@@ -423,7 +430,9 @@ def lm_prefill(cfg: ModelConfig, params, tokens, extras=None,
                 cache_len=cache_len)
             return xx, cache_l
 
-        x, cache = jax.lax.scan(body, x, (params["blocks"], flags))
+        # XLA writes each layer's cache into the stack around the body
+        with jax.named_scope(scopes.ATTENTION_KV_CACHE):
+            x, cache = jax.lax.scan(body, x, (params["blocks"], flags))
     else:
         cache = []
         for i, p_l in enumerate(params["blocks"]):

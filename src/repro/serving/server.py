@@ -53,6 +53,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Union
 
+from .. import scopes
 from ..analysis.locks import make_lock
 from ..core.plan import (
     Plan,
@@ -231,22 +232,26 @@ class PlanServer:
             raise ServerClosed(
                 "PlanServer is not running (use `with PlanServer(...)`"
                 " or call start())")
-        t_start = time.perf_counter()
-        self.telemetry.count("requests")
-        w = self._rehome(w)
-        self.predictor.observe(w, algorithm)
-        key = traffic_fingerprint(w, algorithm)
-        ticket = PlanTicket()
-        plan = self._lookup_live(key, counted=True)
-        if plan is not None:
-            self._resolve_hit(ticket, plan, key, t_start, tier, w, algorithm)
+        with scopes.span(scopes.PLAN_SUBMIT) as span:
+            t_start = time.perf_counter()
+            self.telemetry.count("requests")
+            w = self._rehome(w)
+            self.predictor.observe(w, algorithm)
+            key = traffic_fingerprint(w, algorithm)
+            ticket = PlanTicket()
+            plan = self._lookup_live(key, counted=True)
+            if plan is not None:
+                self._resolve_hit(ticket, plan, key, t_start, tier, w,
+                                  algorithm)
+                span.set_metadata(outcome="hit")
+                return ticket
+            req = PlanRequest(workload=w, algorithm=algorithm, tier=tier,
+                              kind="plan", key=key, ticket=ticket,
+                              t_start=t_start)
+            span.set_metadata(request_id=req.request_id, outcome="queued")
+            self.queue.put(req)  # raises AdmissionError when saturated
+            self.telemetry.observe_queue_depth(self.queue.depth())
             return ticket
-        req = PlanRequest(workload=w, algorithm=algorithm, tier=tier,
-                          kind="plan", key=key, ticket=ticket,
-                          t_start=t_start)
-        self.queue.put(req)  # raises AdmissionError when saturated
-        self.telemetry.observe_queue_depth(self.queue.depth())
-        return ticket
 
     def request(self, w: Workload, algorithm: str = "flash",
                 tier: Tier = Tier.INTERACTIVE,
@@ -501,6 +506,8 @@ class PlanServer:
                         self._inexact.discard(key)
                     self.telemetry.count("expired")
                 continue
+            self.telemetry.observe_queue_wait(
+                req.tier.name, time.perf_counter() - req.t_start)
             self._dying[ident] = req
             with self._lock:
                 self._busy += 1
@@ -549,7 +556,9 @@ class PlanServer:
         try:
             plan = self._lookup_live(key, counted=False)
             if plan is None:
-                plan, source, exact = self._synthesize_best(req)
+                with scopes.span(scopes.PLAN_SYNTHESIZE) as span:
+                    plan, source, exact = self._synthesize_best(req)
+                    span.set_metadata(kind=source)
         except BaseException as e:
             err = e
         finally:
@@ -690,6 +699,10 @@ class PlanServer:
         normal upgrade machinery converges it to the exact plan."""
         if self._lookup_live(req.key, counted=False) is not None:
             return  # a client miss already re-planned this family
+        with scopes.span(scopes.PLAN_SYNTHESIZE, kind="rerepair"):
+            self._rerepair(req)
+
+    def _rerepair(self, req: PlanRequest) -> None:
         scheduler = self._scheduler(req.algorithm)
         prev, w = req.stale_plan, req.workload
         plan: Optional[Plan] = None
@@ -723,22 +736,24 @@ class PlanServer:
 
     def _upgrade(self, req: PlanRequest) -> None:
         """Replace a degraded cache entry with the exact plan."""
-        scheduler = self._scheduler(req.algorithm)
-        plan = scheduler.synthesize(req.workload, fingerprint=req.key)
-        self.telemetry.observe_synthesis(plan.synth_seconds)
-        plan.compile()
-        self._insert(req.key, plan, exact=True)
+        with scopes.span(scopes.PLAN_SYNTHESIZE, kind="upgrade"):
+            scheduler = self._scheduler(req.algorithm)
+            plan = scheduler.synthesize(req.workload, fingerprint=req.key)
+            self.telemetry.observe_synthesis(plan.synth_seconds)
+            plan.compile()
+            self._insert(req.key, plan, exact=True)
         self.telemetry.count("upgrades")
 
     def _prewarm_job(self, req: PlanRequest) -> None:
         """Synthesize a predicted fingerprint ahead of demand."""
         if self._lookup_live(req.key, counted=False) is not None:
             return  # a real request beat the prediction to it
-        scheduler = self._scheduler(req.algorithm)
-        plan = scheduler.synthesize(req.workload, fingerprint=req.key)
-        self.telemetry.observe_synthesis(plan.synth_seconds)
-        plan.compile()
-        self._insert(req.key, plan, exact=True)
+        with scopes.span(scopes.PLAN_SYNTHESIZE, kind="prewarm"):
+            scheduler = self._scheduler(req.algorithm)
+            plan = scheduler.synthesize(req.workload, fingerprint=req.key)
+            self.telemetry.observe_synthesis(plan.synth_seconds)
+            plan.compile()
+            self._insert(req.key, plan, exact=True)
         with self._lock:
             self._prewarmed[req.key] = None
             while len(self._prewarmed) > 1024:

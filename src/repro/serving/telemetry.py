@@ -19,6 +19,8 @@ section 2):
       "repair":    {"count": int, "residual_sum": float,
                     "hist": {"<=0.01": int, ..., ">1": int}},
       "queue":     {"depth": int, "peak_depth": int},
+      "queue_wait": {"count": int, "seconds_sum": float,
+                     "by_tier": {tier_name: {"count", "seconds_sum"}}},
       "fabric":    {"version": int, "events": int,
                     "last_event": str | None},
     }
@@ -109,6 +111,7 @@ class Telemetry:
         self._repair_sum = 0.0
         self._queue_depth = 0
         self._queue_peak = 0
+        self._queue_wait: Dict[str, List[float]] = {}  # tier: [n, sum]
         self._fabric_version = 0
         self._fabric_events = 0
         self._fabric_last: str = ""
@@ -159,6 +162,14 @@ class Telemetry:
             if depth > self._queue_peak:
                 self._queue_peak = int(depth)
 
+    def observe_queue_wait(self, tier_name: str, seconds: float) -> None:
+        """Record how long one request of a tier waited in the queue
+        before a worker took it (from its ``t_start``)."""
+        with self._lock:
+            w = self._queue_wait.setdefault(tier_name, [0, 0.0])
+            w[0] += 1
+            w[1] += float(seconds)
+
     def latency_percentile(self, tier_name: str, q: float) -> float:
         with self._lock:
             res = self._latency.get(tier_name)
@@ -191,6 +202,13 @@ class Telemetry:
                            "hist": repair_hist},
                 "queue": {"depth": self._queue_depth,
                           "peak_depth": self._queue_peak},
+                "queue_wait": {
+                    "count": sum(n for n, _ in self._queue_wait.values()),
+                    "seconds_sum": sum(
+                        s for _, s in self._queue_wait.values()),
+                    "by_tier": {name: {"count": n, "seconds_sum": s}
+                                for name, (n, s) in
+                                self._queue_wait.items()}},
                 "fabric": {"version": self._fabric_version,
                            "events": self._fabric_events,
                            "last_event": self._fabric_last or None},

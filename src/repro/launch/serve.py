@@ -62,6 +62,10 @@ def make_serve_step(cfg: ModelConfig, mesh: Optional[Mesh],
                     a2a_impl: Optional[str] = None, plan=None):
     """jit'd (params, cache, tokens [B], pos) -> (logits [B, V], cache).
 
+    The cache is donated, so the step writes the new token's row in place:
+    the cache passed in is consumed, and the caller goes on with the one
+    returned.
+
     ``a2a_impl`` selects the MoE dispatch schedule through the comm-layer
     registry (flash | direct | hierarchical | plan), defaulting to the
     config's.  ``plan`` is the synthesized Plan/ExecutableSchedule that
@@ -77,8 +81,6 @@ def make_serve_step(cfg: ModelConfig, mesh: Optional[Mesh],
         with use_mesh_rules(rules):
             return model.decode_step(params, cache, tokens, pos, dist)
 
-    if mesh is None:
-        return _Spanned(jax.jit(serve_step), scopes.SERVE_DECODE_STEP)
     return _Spanned(jax.jit(serve_step, donate_argnums=(1,)),
                     scopes.SERVE_DECODE_STEP)
 

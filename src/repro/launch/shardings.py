@@ -88,9 +88,10 @@ _PARAM_TABLE = {
 }
 
 _CACHE_TABLE = {
-    # [*, B, phys, K, dh]
+    # self-attention, head-major [*, B, K, phys, dh]
     "k": ("__dp__", None, None, "model"),
     "v": ("__dp__", None, None, "model"),
+    # cross-attention [B, Se, K, dh]
     "xk": ("__dp__", None, None, "model"),
     "xv": ("__dp__", None, None, "model"),
     # mlstm state

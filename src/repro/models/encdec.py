@@ -157,7 +157,8 @@ def encdec_loss(cfg: ModelConfig, params, batch,
 def encdec_init_cache(cfg: ModelConfig, batch: int, seq_len: int,
                       frames: Optional[jax.Array] = None,
                       params: Optional[dict] = None) -> Any:
-    """Self-attn KV cache (seq_len) + per-layer projected cross KV.
+    """Self-attn KV cache (head-major [B, K, seq_len, Dh]) + per-layer
+    projected cross KV [B, encoder_len, K, Dh].
 
     With ``frames``+``params`` the cross cache holds the real encoder
     projections; otherwise zeros (structural lowering path passes the
@@ -171,8 +172,8 @@ def encdec_init_cache(cfg: ModelConfig, batch: int, seq_len: int,
         enc_out = encode(cfg, params, frames)
     for i in range(cfg.n_layers):
         entry = {
-            "k": jnp.zeros((batch, seq_len, kv, dh), compute),
-            "v": jnp.zeros((batch, seq_len, kv, dh), compute),
+            "k": jnp.zeros((batch, kv, seq_len, dh), compute),
+            "v": jnp.zeros((batch, kv, seq_len, dh), compute),
         }
         if enc_out is not None:
             ek, ev = _project_enc_kv(

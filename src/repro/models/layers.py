@@ -287,19 +287,21 @@ def attention_apply(
 @scopes.scoped(scopes.ATTENTION_KV_CACHE)
 def assemble_kv_cache(k: jax.Array, v: jax.Array, window: Optional[int],
                       cache_len: int) -> Tuple[jax.Array, jax.Array]:
-    """Place prefill keys/values [B, S, K, Dh] into a decode cache of
-    physical length min(cache_len, window or cache_len), ring-aligned so
-    position p lives at slot p % phys (matching attention_decode)."""
-    b, s = k.shape[:2]
+    """Place prefill keys/values [B, S, K, Dh] into a head-major decode
+    cache [B, K, S_phys, Dh], S_phys = min(cache_len, window or cache_len),
+    ring-aligned so position p lives at slot p % S_phys (matching
+    attention_decode)."""
+    s = k.shape[1]
     phys = cache_len if window is None else min(cache_len, window)
 
     def place(x):
+        x = x.swapaxes(1, 2)
         if s >= phys:
-            xw = x[:, s - phys:]
+            xw = x[:, :, s - phys:]
             shift = s % phys
-            return jnp.roll(xw, shift, axis=1) if shift else xw
+            return jnp.roll(xw, shift, axis=2) if shift else xw
         pad = [(0, 0)] * x.ndim
-        pad[1] = (0, phys - s)
+        pad[2] = (0, phys - s)
         return jnp.pad(x, pad)
 
     return place(k), place(v)
@@ -309,16 +311,26 @@ def attention_decode(
     cfg: ModelConfig,
     p: dict,
     x: jax.Array,                   # [B, 1, d]
-    cache_k: jax.Array,             # [B, S_phys, K, Dh]
+    cache_k: jax.Array,             # [B, K, S_phys, Dh], or [L, B, K, ...]
     cache_v: jax.Array,
     pos: jax.Array,                 # scalar: index of the new token
     *,
     window: Optional[int] = None,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode against a (ring-buffered, if windowed) KV cache."""
+    """One-token decode against a (ring-buffered, if windowed) KV cache.
+
+    The cache is head-major: each kv head's [S_phys, Dh] slots are one
+    block, the operand order of the grouped-query dots on a TPU.  With
+    ``layer`` the caches are the whole layer stack, read and written at
+    ``layer``.  Attention reads the slots as they were before this step
+    and takes the new token's key and value from the projection; the new
+    row is written last, in place.  A read after the write would keep XLA
+    from fusing the layer's slice into the dots, which would then copy it.
+    Returns the output and the updated caches."""
     b = x.shape[0]
     h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    s_phys = cache_k.shape[1]
+    s_phys = cache_k.shape[-2]
     positions = jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32)
     q, k_new, v_new = _project_qkv(cfg, p, x, positions=positions)
     # Decode shards the KV cache over head_dim ("kv_feature" -> model); q
@@ -332,29 +344,43 @@ def attention_decode(
     # so ring-buffer slot order is irrelevant (softmax is permutation
     # invariant over kv slots).
     slot = pos if window is None else pos % s_phys
-    with jax.named_scope(scopes.ATTENTION_KV_CACHE):
-        cache_k = jax.lax.dynamic_update_index_in_dim(
-            cache_k, k_new[:, 0], slot, axis=1)
-        cache_v = jax.lax.dynamic_update_index_in_dim(
-            cache_v, v_new[:, 0], slot, axis=1)
+    k_l, v_l = cache_k, cache_v
+    if layer is not None:
+        with jax.named_scope(scopes.ATTENTION_KV_CACHE):
+            k_l = jax.lax.dynamic_index_in_dim(cache_k, layer, keepdims=False)
+            v_l = jax.lax.dynamic_index_in_dim(cache_v, layer, keepdims=False)
     # Grouped-query einsum against the raw cache: materializing the GQA
     # repeat would force an all-gather of the dh-sharded cache.
     g = h // kv
     with jax.named_scope(scopes.ATTENTION_CORE):
         q5 = q.reshape(b, 1, kv, g, dh)
-        scores = jnp.einsum("bqkgd,bskd->bqkgs", q5, cache_k).astype(
+        scores = jnp.einsum("bqkgd,bksd->bqkgs", q5, k_l).astype(
             jnp.float32) / np.sqrt(dh)
-        # Valid slots: the min(pos + 1, s_phys) most recent positions.  For
-        # the windowed ring buffer (s_phys == window) every written slot is
-        # in-window by construction.
+        own = jnp.einsum("bqkgd,bqkd->bqkg", q5, k_new).astype(
+            jnp.float32)[..., None] / np.sqrt(dh)
+        # Valid slots: the min(pos, s_phys) most recent earlier positions,
+        # less the slot this token takes (the ring's oldest, now out of the
+        # window).  For the windowed ring buffer (s_phys == window) every
+        # written slot is in-window by construction.
         idx = jnp.arange(s_phys)
-        valid = idx < jnp.minimum(pos + 1, s_phys)
+        valid = (idx < jnp.minimum(pos, s_phys)) & (idx != slot)
         scores = jnp.where(valid[None, None, None, None, :], scores,
                            NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bqkgs,bskd->bqkgd", probs, cache_v)
+        probs = jax.nn.softmax(jnp.concatenate([scores, own], -1),
+                               axis=-1).astype(x.dtype)
+        out = jnp.einsum("bqkgs,bksd->bqkgd", probs[..., :-1], v_l)
+        out = (out.astype(jnp.float32) + probs[..., -1:].astype(jnp.float32)
+               * v_new[:, :, :, None].astype(jnp.float32)).astype(x.dtype)
     with jax.named_scope(scopes.ATTENTION_PROJ):
         out = out.reshape(b, 1, h * dh) @ p["wo"].astype(x.dtype)
+    with jax.named_scope(scopes.ATTENTION_KV_CACHE):
+        # the new rows [B, K, 1, Dh], at ((layer,) 0, 0, slot, 0)
+        k_row, v_row = k_new.swapaxes(1, 2), v_new.swapaxes(1, 2)
+        at = (0, 0, slot, 0)
+        if layer is not None:
+            k_row, v_row, at = k_row[None], v_row[None], (layer,) + at
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k_row, at)
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v_row, at)
     return out, cache_k, cache_v
 
 

@@ -303,9 +303,10 @@ def _zero_cache_block(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
     if kind == "s":
         return {"state": slstm_zero_state(cfg, batch)}
     phys = _phys_len(cfg, seq_len, full_attn)
+    # head-major, the order in which attention_decode's dots read it
     c = {
-        "k": jnp.zeros((batch, phys, kv, dh), compute),
-        "v": jnp.zeros((batch, phys, kv, dh), compute),
+        "k": jnp.zeros((batch, kv, phys, dh), compute),
+        "v": jnp.zeros((batch, kv, phys, dh), compute),
     }
     if kind == "hybrid":
         c["ssm"] = mamba_zero_state(cfg, batch)
@@ -331,7 +332,11 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq_len: int) -> Any:
 
 @scopes.scoped(scopes.TRANSFORMER_BLOCK)
 def _block_decode(cfg: ModelConfig, p: dict, cache: dict, x, pos, *,
-                  kind: str, full_flag, dist) -> Tuple[jax.Array, dict]:
+                  kind: str, full_flag, dist,
+                  layer=None) -> Tuple[jax.Array, dict]:
+    """One layer's decode step.  With ``layer``, ``cache["k"]`` /
+    ``cache["v"]`` are the whole layer stack, written in place at
+    ``layer`` (``attention_decode``); every other leaf is this layer's."""
     if kind == "m":
         y, st = mlstm_decode(cfg, p["mlstm"],
                              norm_apply(cfg, p["norm1"], x), cache["state"])
@@ -343,14 +348,15 @@ def _block_decode(cfg: ModelConfig, p: dict, cache: dict, x, pos, *,
     window = None
     if cfg.swa_window is not None:
         is_full = full_flag if isinstance(full_flag, bool) else False
-        phys = cache["k"].shape[1]
+        phys = cache["k"].shape[-2]
         # ring semantics engage only when the cache is window-sized
         window = cfg.swa_window if (not is_full and
                                     phys <= cfg.swa_window) else None
     h = norm_apply(cfg, p["norm1"], x)
     new_cache = dict(cache)
     attn, new_cache["k"], new_cache["v"] = attention_decode(
-        cfg, p["attn"], h, cache["k"], cache["v"], pos, window=window)
+        cfg, p["attn"], h, cache["k"], cache["v"], pos, window=window,
+        layer=layer)
     if kind == "hybrid":
         ssm, new_cache["ssm"] = mamba_decode(cfg, p["mamba"], h, cache["ssm"])
         x = x + 0.5 * (norm_apply(cfg, p["fuse_norm_attn"], attn)
@@ -375,18 +381,28 @@ def lm_decode_step(cfg: ModelConfig, params, cache, tokens, pos,
     if cfg.scan_layers:
         flags = _full_flags(cfg)
 
-        def body(xx, inp):
-            p_l, cache_l, flag_l = inp
-            xx, new_cache_l = _block_decode(
-                cfg, p_l, cache_l, xx, pos, kind=kinds[0],
-                full_flag=flag_l, dist=dist)
-            return xx, new_cache_l
+        def body(carry, inp):
+            xx, stack, l = carry
+            p_l, flag_l = inp
+            # K/V stay stacked and take one row in place; the small
+            # per-layer states (SSM, xLSTM) go in and out as layer slices
+            kv = {n: a for n, a in stack.items() if n in ("k", "v")}
+            small = {n: a for n, a in stack.items() if n not in kv}
+            here = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, l, keepdims=False), small)
+            xx, new = _block_decode(
+                cfg, p_l, {**here, **kv}, xx, pos, kind=kinds[0],
+                full_flag=flag_l, dist=dist, layer=l)
+            small = jax.tree.map(
+                lambda a, b: jax.lax.dynamic_update_index_in_dim(a, b, l, 0),
+                small, {n: new[n] for n in small})
+            return (xx, {**small, **{n: new[n] for n in kv}}, l + 1), None
 
-        # XLA slices each layer's cache out of the stack and writes it
-        # back around the body: that is cache traffic too.
+        # The stacked cache is carried, not sliced out and stacked back:
+        # with the cache donated, the loop updates it in place.
         with jax.named_scope(scopes.ATTENTION_KV_CACHE):
-            x, new_cache = jax.lax.scan(body, x,
-                                        (params["blocks"], cache, flags))
+            (x, new_cache, _), _ = jax.lax.scan(
+                body, (x, cache, jnp.int32(0)), (params["blocks"], flags))
     else:
         new_cache = []
         for i, (p_l, cache_l) in enumerate(zip(params["blocks"], cache)):

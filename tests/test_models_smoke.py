@@ -10,8 +10,20 @@ import pytest
 
 from repro.configs import list_archs, smoke_config
 from repro.models import build_model
+from repro.models.transformer import layer_kinds
 
 B, S = 2, 16
+
+
+def _with_scan(archs):
+    """(arch, scan_layers) cases: every arch unrolled, as its smoke config
+    has it, and the homogeneous ones also scanned over stacked layers."""
+    cases = []
+    for arch in archs:
+        cases.append(pytest.param(arch, False, id=arch))
+        if len(set(layer_kinds(smoke_config(arch)))) == 1:
+            cases.append(pytest.param(arch, True, id=f"{arch}-scan"))
+    return cases
 
 
 def _batch(cfg, key=0):
@@ -55,13 +67,14 @@ def test_decode_step_smoke(arch):
     assert bool(jnp.isfinite(logits).all()), f"{arch}: decode NaN"
 
 
-@pytest.mark.parametrize("arch", [
+@pytest.mark.parametrize("arch,scan", _with_scan([
     "llama3.2-1b", "qwen3-0.6b", "mixtral-8x7b", "dbrx-132b",
     "xlstm-125m", "hymba-1.5b", "granite-3-2b", "mistral-large-123b",
-    "megatron-moe-32e"])
-def test_decode_matches_forward(arch):
+    "megatron-moe-32e"]))
+def test_decode_matches_forward(arch, scan):
     """Teacher-forced decode chain reproduces the training forward."""
-    cfg = dataclasses.replace(smoke_config(arch), compute_dtype="float32")
+    cfg = dataclasses.replace(smoke_config(arch), compute_dtype="float32",
+                              scan_layers=scan)
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(1))
     toks = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab)
@@ -76,10 +89,11 @@ def test_decode_matches_forward(arch):
         assert err < 1e-5, (arch, t, err)
 
 
-@pytest.mark.parametrize("arch", ["llama3.2-1b", "mixtral-8x7b",
-                                  "xlstm-125m", "hymba-1.5b"])
-def test_prefill_then_decode(arch):
-    cfg = dataclasses.replace(smoke_config(arch), compute_dtype="float32")
+@pytest.mark.parametrize("arch,scan", _with_scan([
+    "llama3.2-1b", "mixtral-8x7b", "xlstm-125m", "hymba-1.5b"]))
+def test_prefill_then_decode(arch, scan):
+    cfg = dataclasses.replace(smoke_config(arch), compute_dtype="float32",
+                              scan_layers=scan)
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(1))
     toks = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab)
@@ -95,11 +109,13 @@ def test_prefill_then_decode(arch):
         assert err < 1e-5, (arch, t, err)
 
 
-def test_sliding_window_masks_history():
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
+def test_sliding_window_masks_history(scan):
     """A windowed arch must ignore tokens beyond the window."""
     cfg = dataclasses.replace(smoke_config("mixtral-8x7b"),
                               compute_dtype="float32", swa_window=4,
-                              n_layers=1, moe=None, family="dense")
+                              n_layers=1, moe=None, family="dense",
+                              scan_layers=scan)
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (1, 12), 0, cfg.vocab)
@@ -112,6 +128,42 @@ def test_sliding_window_masks_history():
     assert float(jnp.abs(base[0, -1] - pert[0, -1]).max()) < 1e-5
     # position 2 sees token 1: changed
     assert float(jnp.abs(base[0, 2] - pert[0, 2]).max()) > 1e-6
+
+
+def test_scanned_step_matches_unrolled_through_the_ring():
+    """The scanned serve step, which carries the stacked cache through the
+    layer scan and writes each new row in place (the cache donated), gives
+    the unrolled step's logits and caches over 8 steps in float32, through
+    a 4-slot ring that wraps; both follow the training forward."""
+    from repro.launch.serve import make_serve_step
+    from repro.models.transformer import lm_forward
+
+    n = 8
+    base = dataclasses.replace(smoke_config("mixtral-8x7b"),
+                               compute_dtype="float32", swa_window=4)
+    scan_cfg = dataclasses.replace(base, scan_layers=True)
+    roll_cfg = dataclasses.replace(base, scan_layers=False)
+    params = build_model(scan_cfg).init(jax.random.PRNGKey(1))
+    unrolled = dict(params, blocks=[
+        jax.tree.map(lambda a, i=i: a[i], params["blocks"])
+        for i in range(base.n_layers)])
+    toks = jax.random.randint(jax.random.PRNGKey(2), (B, n), 0, base.vocab)
+    logits_fwd, _ = lm_forward(roll_cfg, unrolled, toks)
+    scale = float(jnp.abs(logits_fwd).max()) + 1e-9
+    cache_s = build_model(scan_cfg).init_cache(B, n)
+    cache_r = build_model(roll_cfg).init_cache(B, n)
+    assert cache_s["k"].shape == (base.n_layers, B, base.n_kv_heads, 4,
+                                  base.resolved_head_dim)
+    step_s = make_serve_step(scan_cfg, None)
+    step_r = make_serve_step(roll_cfg, None)
+    for t in range(n):
+        lg_s, cache_s = step_s(params, cache_s, toks[:, t], jnp.int32(t))
+        lg_r, cache_r = step_r(unrolled, cache_r, toks[:, t], jnp.int32(t))
+        assert float(jnp.abs(lg_s - lg_r).max()) / scale < 1e-5, t
+        assert float(jnp.abs(lg_s - logits_fwd[:, t]).max()) / scale < 1e-5
+    for name in ("k", "v"):
+        stacked = jnp.stack([c[name] for c in cache_r])
+        assert float(jnp.abs(cache_s[name] - stacked).max()) < 1e-5, name
 
 
 def test_vlm_patch_prefix_used():

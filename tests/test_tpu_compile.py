@@ -11,6 +11,7 @@ given this file loads the TPU library, and it compiles in its own process.
 
 import importlib.util
 import os
+import re
 from functools import partial
 
 import jax
@@ -128,3 +129,50 @@ def test_megatron_prefill_fits_one_chip(one_chip, smoke, cfg):
     assert mem.argument_size_in_bytes < V5E_HBM_BYTES
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < V5E_HBM_BYTES
+
+
+# mixtral-8x7b-2l, the decode cell's configuration, and its traffic: 64
+# streams of 128 prompt and 512 generated tokens
+MIXTRAL_2L = dict(n_layers=2, d_model=4096, n_heads=32, n_kv_heads=8,
+                  head_dim=128, d_ff=14336, vocab=32000, swa_window=4096,
+                  param_dtype="bfloat16", compute_dtype="bfloat16")
+DECODE_BATCH, DECODE_LEN = 64, 128 + 512
+
+
+def test_decode_step_updates_the_cache_in_place(one_chip):
+    """The served decode step at the decode cell's shapes aliases the
+    donated cache, copies none of it (neither a layer nor the stack, nor
+    the layer's slice into a temporary): only the new rows are written."""
+    from repro.configs import get_config
+    from repro.launch.serve import make_serve_step
+    from repro.models import build_model
+
+    cfg = get_config("mixtral-8x7b", **MIXTRAL_2L)
+    model = build_model(cfg)
+    on_chip = partial(jax.tree.map, lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip))
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_cache(DECODE_BATCH, DECODE_LEN)))
+    layer = (DECODE_BATCH, cfg.n_kv_heads, DECODE_LEN, cfg.resolved_head_dim)
+    assert cache["k"].shape == (cfg.n_layers,) + layer
+    toks = jax.ShapeDtypeStruct((DECODE_BATCH,), jnp.int32,
+                                sharding=one_chip)
+    compiled = make_serve_step(cfg, None).lower(
+        params, cache, toks, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    layer_k_bytes = cache_bytes // (2 * cfg.n_layers)
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < layer_k_bytes
+    dims = ",".join(map(str, layer))
+    cache_shape = re.compile(rf"\[(\d+,)?{dims}\]")
+    copies = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w\-]+)\(", line)
+        if m and cache_shape.search(m.group(2)) and (
+                m.group(3) in ("copy", "copy-start")
+                or m.group(1).startswith("copy")):
+            copies.append(line)
+    assert not copies, copies

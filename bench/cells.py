@@ -7,7 +7,12 @@ the only place that maps a name to its file, so a later cell or metric is
 added by adding files and entries, never by editing code.
 
 * ``BENCHMARK.json``             the cells, metrics and bounds
-* ``bench/configs/<c>.json``     model sizes as run, source, cuts
+* ``bench/configs/<c>.json``     model sizes as run, source, cuts, and
+                                 the ``arch`` the model is built of
+* ``bench/arch/<a>.py``          an architecture: its parameter tree,
+                                 the sizes the program must agree on,
+                                 the plain forward of each kind of layer,
+                                 its routing and its useful FLOPs
 * ``bench/traffic/<t>.json``     traffic parameters, read by one generator
 * ``bench/workloads/<w>.json``   a cell: configuration, traffic, chips,
                                  mesh, exchange, correctness limits
@@ -21,6 +26,7 @@ run holds nothing for it to read, and the metric is left out.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import os
@@ -51,6 +57,14 @@ def load_config(name: str) -> dict:
 
 def load_traffic(name: str) -> dict:
     return _load_json("traffic", name)
+
+
+def load_arch(name: str):
+    """The architecture module ``bench/arch/<name>.py`` (``moe_gqa``, ...),
+    imported once per process."""
+    if not name.isidentifier():
+        raise ValueError(f"architecture name {name!r} is no module name")
+    return importlib.import_module(f"bench.arch.{name}")
 
 
 @dataclasses.dataclass

@@ -22,7 +22,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from . import cells, flops, peaks, trace_reduce
+from . import cells, peaks, trace_reduce
 from .compile_log import CompileLog
 from .seeds import seed_seq
 from .traffic.moe_traffic import DriftStream, moe_matrix
@@ -53,6 +53,7 @@ class Run:
     """Everything a metric reader may read; see ``bench/metrics``."""
 
     cell: cells.Cell
+    arch: object
     dims: dict
     chips: int
     peaks: dict
@@ -70,6 +71,7 @@ class Run:
     trace_lo: float = 0.0
     trace_hi: float = 0.0
     trace_devices: List[int] = dataclasses.field(default_factory=list)
+    plan: object = None
 
 
 class _Closed(Exception):
@@ -83,10 +85,10 @@ class RequestPool:
     batch's last tokens reach the host.  Each call's tokens are fetched
     as they are produced; a token counts when it reaches the host."""
 
-    def __init__(self, serve, prefill, step, params, put, dims: dict,
-                 traffic: dict, run: Run):
+    def __init__(self, serve, prefill, step, params, put, traffic: dict,
+                 run: Run):
         self.serve, self.prefill, self.step = serve, prefill, step
-        self.params, self.put, self.dims = params, put, dims
+        self.params, self.put = params, put
         self.batch = traffic["batch"]
         self.prompt_len = traffic["prompt_len"]
         self.gen_len = traffic["gen_len"]
@@ -140,12 +142,12 @@ class RequestPool:
                 break
             if i == 0:
                 run.tokens += b * p + b
-                run.useful_flops += flops.prefill_flops(self.dims, b, p)
+                run.useful_flops += run.arch.prefill_flops(run.dims, b, p)
                 run.ttft_s.extend([t - t_issue] * b)
             else:
                 run.tokens += b
-                run.useful_flops += flops.decode_step_flops(
-                    self.dims, b, p + i - 1)
+                run.useful_flops += run.arch.decode_step_flops(
+                    run.dims, b, p + i - 1)
                 run.tpot_s.append(t - arrivals[i - 1])
 
     def warm_up(self, prompts: np.ndarray) -> None:
@@ -295,40 +297,40 @@ class PlanStream:
 RUN_KEYS = ("n_layers", "param_dtype", "compute_dtype")
 
 
+def _replace(obj, key: str, value):
+    """``obj`` with the field ``key`` set; ``a.b`` sets ``b`` of field
+    ``a``."""
+    head, _, rest = key.partition(".")
+    if rest:
+        value = _replace(getattr(obj, head), rest, value)
+    return dataclasses.replace(obj, **{head: value})
+
+
 def model_config(cell: cells.Cell):
     """The program's ModelConfig for the cell's configuration file; every
-    size in the file must be the registry's or one of its listed cuts."""
+    size in the file must be the registry's or one of its listed cuts.
+    The file's ``program`` entries set the program's own options (a
+    dotted key sets a field of a nested one); its architecture module
+    says which sizes of the program's configuration the file states."""
     from repro.configs import get_config, smoke_config
 
     conf = cell.config
     model = conf["model"]
-    over = {k: model[k] for k in RUN_KEYS}
-    program = dict(conf.get("program", {}))
-    factor = program.pop("capacity_factor", None)
     base = smoke_config if conf.get("smoke") else get_config
-    cfg = base(conf["registry"], **over, **program)
-    if factor is not None:
-        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-            cfg.moe, capacity_factor=factor))
-    have = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff,
-            "vocab": cfg.vocab, "num_experts": cfg.moe.num_experts,
-            "top_k": cfg.moe.top_k,
-            "capacity_factor": cfg.moe.capacity_factor,
-            "rope_theta": cfg.rope_theta, "swa_window": cfg.swa_window,
-            "norm": cfg.norm, "act": cfg.act,
-            "tie_embeddings": cfg.tie_embeddings,
-            "param_dtype": cfg.param_dtype,
-            "compute_dtype": cfg.compute_dtype}
-    bad = {k: (model[k], have[k]) for k in have if model.get(k) != have[k]}
+    cfg = base(conf["registry"])
+    for key, value in [*((k, model[k]) for k in RUN_KEYS),
+                       *conf.get("program", {}).items()]:
+        cfg = _replace(cfg, key, value)
+    have = cells.load_arch(conf["arch"]).program_sizes(cfg)
+    bad = {k: (model.get(k), have[k]) for k in have if model.get(k) != have[k]}
     if bad:
         raise ValueError(f"configuration {conf['name']}: file vs program "
                          f"(file, program): {bad}")
     return cfg
 
 
-def daemon_plan(cfg, cluster_shape, batch: int, prompt_len: int, seed: int):
+def daemon_plan(arch, dims: dict, cluster_shape, batch: int,
+                prompt_len: int, seed: int):
     """The exchange's plan from the plan daemon, as ``chip_smoke.py``
     gets it: the dispatch matrix of one prefill batch."""
     from repro.core.traffic import ClusterSpec, Workload
@@ -337,15 +339,48 @@ def daemon_plan(cfg, cluster_shape, batch: int, prompt_len: int, seed: int):
     pods, fast = cluster_shape
     cluster = ClusterSpec(pods, fast)
     n = pods * fast
-    mat = moe_matrix(n, batch * prompt_len // n, cfg.d_model * 2,
-                     top_k=cfg.moe.top_k, n_experts=cfg.moe.num_experts,
-                     seed=seed_seq(seed, "exchange"))
+    top_k, n_experts, token_bytes = arch.dispatch(dims)
+    mat = moe_matrix(n, batch * prompt_len // n, token_bytes, top_k=top_k,
+                     n_experts=n_experts, seed=seed_seq(seed, "exchange"))
     with PlanServer(workers=1) as srv:
         client = PlanClient(srv, algorithm="flash", inline_fallback=False)
         answer, sched = client.get_device_schedule(Workload(cluster, mat))
     log(f"plan: daemon answered ({answer.source}) for {pods}x{fast}; "
         f"{sched.n_stages} ppermute stages, pairs {list(sched.pairs)}")
     return answer.plan
+
+
+def placement(cell: cells.Cell, cfg, tree, device):
+    """(mesh or None, the parameters' shardings, the prompts' sharding) of
+    the cell's served path: the cell's mesh, else ``device``."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.launch.mesh import make_mesh
+    from repro.launch.shardings import batch_shardings, param_shardings
+
+    from . import weights
+
+    shapes = weights.shapes(tree)
+    if not cell.mesh:
+        one = SingleDeviceSharding(device)
+        return None, jax.tree.map(lambda _: one, shapes), one
+    mesh = make_mesh(tuple(cell.mesh["shape"]), tuple(cell.mesh["axes"]))
+    tokens = jax.ShapeDtypeStruct(
+        (cell.traffic["batch"], cell.traffic["prompt_len"]), np.int32)
+    return (mesh, param_shardings(cfg, mesh, shapes),
+            batch_shardings(mesh, {"tokens": tokens})["tokens"])
+
+
+def served_steps(cell: cells.Cell, cfg, mesh, plan):
+    """The prefill step and the decode step, as the window runs them."""
+    from repro.launch import serve
+
+    traffic = cell.traffic
+    cache_len = traffic["prompt_len"] + traffic["gen_len"]
+    return (serve.make_prefill_step(cfg, mesh, cell.a2a_impl, plan=plan,
+                                    cache_len=cache_len),
+            serve.make_serve_step(cfg, mesh, cell.a2a_impl, plan=plan))
 
 
 # -- the run --------------------------------------------------------------------
@@ -360,8 +395,6 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devices,
 
     from repro.launch import serve
     from repro.launch.compile_cache import enable_compile_cache
-    from repro.launch.mesh import make_mesh
-    from repro.launch.shardings import batch_shardings, param_shardings
     from repro.models import build_model
 
     from . import weights
@@ -372,38 +405,29 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devices,
     dev0 = devices[0]
     traffic = cell.traffic
     cfg = model_config(cell)
+    arch = cells.load_arch(cell.config["arch"])
     dims = cell.config["model"]
-    run_rec = Run(cell=cell, dims=dims, chips=cell.chips,
+    tree = arch.param_tree(dims)
+    run_rec = Run(cell=cell, arch=arch, dims=dims, chips=cell.chips,
                   peaks=peaks.peaks_for(dev0.device_kind)
                   if dev0.platform == "tpu" else {})
 
     # weights, mesh, plan, programs
     layout = weights.check_layout(
-        jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0)), dims)
+        jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0)), tree)
     if layout:
         raise ValueError(layout)
-    mesh = None
-    if cell.mesh:
-        mesh = make_mesh(tuple(cell.mesh["shape"]), tuple(cell.mesh["axes"]))
-        shard = param_shardings(cfg, mesh, weights.param_shapes(dims))
-        params = weights.make_params(dims, seed, out_shardings=shard)
-        tok_sh = batch_shardings(mesh, {"tokens": jax.ShapeDtypeStruct(
-            (traffic["batch"], traffic["prompt_len"]), np.int32)})["tokens"]
-    else:
-        params = weights.make_params(dims, seed, device=dev0)
-        tok_sh = dev0
+    mesh, param_sh, tok_sh = placement(cell, cfg, tree, dev0)
+    params = weights.make_params(tree, seed, out_shardings=param_sh)
     jax.block_until_ready(params)
     plan = None
     if cell.a2a_impl == "plan":
-        plan = daemon_plan(cfg, cell.plan_cluster, traffic["batch"],
+        plan = daemon_plan(arch, dims, cell.plan_cluster, traffic["batch"],
                            traffic["prompt_len"], seed)
-    cache_len = traffic["prompt_len"] + traffic["gen_len"]
-    prefill = serve.make_prefill_step(cfg, mesh, cell.a2a_impl, plan=plan,
-                                      cache_len=cache_len)
-    step = serve.make_serve_step(cfg, mesh, cell.a2a_impl, plan=plan)
+    run_rec.plan = plan
+    prefill, step = served_steps(cell, cfg, mesh, plan)
     pool = RequestPool(serve, prefill, step, params,
-                       lambda a: jax.device_put(a, tok_sh), dims, traffic,
-                       run_rec)
+                       lambda a: jax.device_put(a, tok_sh), traffic, run_rec)
     s = traffic["prompt_tokens"]["s"]
     pool.warm_up(ZipfPrompts(dims["vocab"], s, seed_seq(seed, "warm"))
                  .batch(traffic["batch"], traffic["prompt_len"]))
@@ -463,7 +487,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devices,
     jax.clear_caches()
 
     checks, info = check.check_outputs(
-        cell, dims, seed, completed, devices[:cell.chips],
+        cell, arch, dims, seed, completed, devices[:cell.chips],
         stream.answers if stream else None,
         unanswered=waiting,
         m_gpus=traffic["plan_stream"]["m_gpus"] if stream else 0,

@@ -1,11 +1,12 @@
 """Share of the HBM roofline reached by the ``kernels/a2a_pack`` Pallas
-kernels (pack and unpack, the only ``tpu_custom_call`` ops on the served
-path): the bytes each call must move, from its shapes (``flops.py``), over
-the HBM peak, over the calls' device time, in percent.  The kernels only
-copy, so bandwidth bounds them."""
+kernels, the ops named ``a2a_pack`` and ``a2a_unpack``: the bytes each
+call must move, from its shapes (``flops.py``), over the HBM peak, over
+the calls' device time, in percent.  The kernels only copy, so bandwidth
+bounds them."""
 
 from bench import flops, trace_reduce
 
+KERNELS = ("a2a_pack", "a2a_unpack")
 _ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s8": 1, "u8": 1, "s32": 4}
 
 
@@ -29,7 +30,8 @@ def read(run):
     for dev in run.trace_devices:
         for ev in run.trace.device_ops.get(dev, ()):
             if not (run.trace_lo <= ev.start_ns < run.trace_hi
-                    and trace_reduce.is_tpu_kernel(ev.name)):
+                    and trace_reduce.instruction(ev.name).rsplit(".", 1)[0]
+                    in KERNELS):
                 continue
             b = _bytes(ev.name)
             if b is None:

@@ -1,16 +1,19 @@
-"""Device milliseconds per batch of the exchange's collectives
-(collective-permute and all-to-all ops, their own time), averaged over
-the cell's chips."""
+"""Device milliseconds per batch of the expert exchange: the own time of
+the ops under the program's ``exchange.pack``, ``exchange.stage`` and
+``exchange.unpack`` scopes (the plan's slot packing, its all-to-all and
+ppermute stages, and the unpacking; matched through the compiled HLO),
+averaged over the cell's chips, over the batches of the window."""
 
-from bench import trace_reduce
+from bench import program_trace
 
 
 def read(run):
-    if run.trace is None or not run.trace_devices or not run.batches:
+    own = program_trace.run_scope_seconds(run) if run.batches else None
+    if own is None:
         return None
-    own = trace_reduce.op_self_seconds(
-        run.trace, run.trace_devices, run.trace_lo, run.trace_hi,
-        select=trace_reduce.is_collective)
-    if not own:
+    s = program_trace.program_scopes()
+    seconds = sum(own.get(name, 0.0) for name in (
+        s.EXCHANGE_PACK, s.EXCHANGE_STAGE, s.EXCHANGE_UNPACK))
+    if seconds <= 0:
         return None
-    return 1e3 * sum(own.values()) / run.batches
+    return 1e3 * seconds / run.batches

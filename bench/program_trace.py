@@ -21,12 +21,13 @@ inside and outside the plan daemon's spans, on any thread.
 
 As a script, on a kept trace::
 
-    python3 bench/program_trace.py <trace dir> [--workload <cell>]
+    python3 bench/program_trace.py <trace dir> [--workload <cell>] [--seed <n>]
 
 prints one JSON object: idle by the window thread's spans, idle inside
 and outside the daemon's spans, the mean step dispatch and, with
-``--workload`` (which compiles the cell's programs where it runs), device
-seconds by scope.
+``--workload`` (which compiles the cell's programs where it runs, on as
+many chips as the cell; ``--seed``, the run's, where the daemon's plan
+drives the exchange), device seconds by scope.
 """
 
 from __future__ import annotations
@@ -191,47 +192,54 @@ def by_scope(seconds: Dict[Tuple[Optional[str], str], float]
     return dict(out)
 
 
-def cell_lowered(cell) -> list:
+def cell_lowered(cell, plan=None) -> list:
     """The cell's prefill step and, where it decodes, its decode step,
-    lowered as the harness runs them: arguments committed to the first
-    device (the position excepted), since that changes the lowered module
-    and so the persistent cache's key.  None for a cell on a mesh."""
+    lowered as the harness runs them (with ``plan``, the exchange's plan
+    of the run): arguments placed as the harness places them, committed
+    to the first device or sharded over the cell's mesh (the position
+    excepted), since that changes the lowered module and so the
+    persistent cache's key.  The decode step takes the cache where the
+    compiled prefill step leaves it."""
     import jax
     import numpy as np
-    from jax.sharding import SingleDeviceSharding
 
-    from repro.launch import serve
+    from bench import cells, harness, weights
 
-    from bench import harness, weights
-
-    if cell.mesh or cell.a2a_impl == "plan":
-        return []
-    traffic, dims = cell.traffic, cell.config["model"]
+    traffic = cell.traffic
     cfg = harness.model_config(cell)
+    tree = cells.load_arch(cell.config["arch"]).param_tree(
+        cell.config["model"])
     b, s, g = traffic["batch"], traffic["prompt_len"], traffic["gen_len"]
-    on_device = SingleDeviceSharding(jax.devices()[0])
+    mesh, param_sh, tok_sh = harness.placement(cell, cfg, tree,
+                                               jax.devices()[0])
+    prefill, step = harness.served_steps(cell, cfg, mesh, plan)
 
-    def committed(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=on_device), tree)
+    def placed(tree_, shardings):
+        return jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sh), tree_, shardings)
 
-    prefill = serve.make_prefill_step(cfg, None, cell.a2a_impl,
-                                      cache_len=s + g)
-    params = committed(weights.param_shapes(dims))
-    batch = committed({"tokens": jax.ShapeDtypeStruct((b, s), np.int32)})
+    params = placed(weights.shapes(tree), param_sh)
+    batch = {"tokens": jax.ShapeDtypeStruct((b, s), np.int32,
+                                            sharding=tok_sh)}
     out = [prefill.lower(params, batch)]
     if g > 1:
-        step = serve.make_serve_step(cfg, None, cell.a2a_impl)
-        cache = committed(jax.eval_shape(prefill, params, batch)[1])
-        toks = committed(jax.ShapeDtypeStruct((b,), np.int32))
+        if mesh is not None:
+            raise NotImplementedError(
+                f"{cell.name}: the placement of a decode step's cache on "
+                f"a mesh")
+        cache = jax.eval_shape(prefill, params, batch)[1]
+        toks = jax.ShapeDtypeStruct((b,), np.int32)
         pos = jax.ShapeDtypeStruct((), np.int32)
-        out.append(step.lower(params, cache, toks, pos))
+        out.append(step.lower(
+            params, placed(cache, jax.tree.map(lambda _: tok_sh, cache)),
+            placed(toks, tok_sh), pos))
     return out
 
 
-def cell_programs(cell) -> List[str]:
+def cell_programs(cell, plan=None) -> List[str]:
     """Compiled HLO text of ``cell_lowered``'s programs."""
-    return [lowered.compile().as_text() for lowered in cell_lowered(cell)]
+    return [lowered.compile().as_text()
+            for lowered in cell_lowered(cell, plan)]
 
 
 # by cell name: a reader gets only the run, and the readers of one run
@@ -239,9 +247,10 @@ def cell_programs(cell) -> List[str]:
 _TABLES: Dict[str, Dict[str, Optional[str]]] = {}
 
 
-def cell_scope_table(cell) -> Dict[str, Optional[str]]:
-    """``hlo_scopes`` of the cell's programs, made once per process; empty
-    where the program names no scopes.
+def cell_scope_table(cell, plan=None) -> Dict[str, Optional[str]]:
+    """``hlo_scopes`` of the cell's programs (with ``plan``, the run's
+    exchange plan), made once per process; empty where the program names
+    no scopes.
 
     A persistent compile cache keyed without metadata may hold the same
     programs compiled from a tree that names no scopes: set-up then loaded
@@ -255,14 +264,14 @@ def cell_scope_table(cell) -> Dict[str, Optional[str]]:
     if names is None:
         return {}
     if cell.name not in _TABLES:
-        ran = cell_programs(cell)
+        ran = cell_programs(cell, plan)
         table = hlo_scopes(ran, names.DEVICE_SCOPES)
         flag = "jax_compilation_cache_include_metadata_in_key"
         if table and not any(table.values()) and not getattr(jax.config,
                                                              flag):
             jax.config.update(flag, True)
             try:
-                scoped = cell_programs(cell)
+                scoped = cell_programs(cell, plan)
             finally:
                 jax.config.update(flag, False)
             table = aligned_scopes(ran, scoped, names.DEVICE_SCOPES) \
@@ -271,20 +280,28 @@ def cell_scope_table(cell) -> Dict[str, Optional[str]]:
     return _TABLES[cell.name]
 
 
+def run_scope_seconds(run) -> Optional[Dict[Optional[str], float]]:
+    """Own device seconds of the traced window by program scope (None for
+    ops under none), averaged over the run's chips; None where the
+    program names no scopes or the run has no trace."""
+    if (program_scopes() is None or run.trace is None
+            or not run.trace_devices):
+        return None
+    table = cell_scope_table(run.cell, run.plan)
+    if not table:
+        return None
+    return by_scope(scope_seconds(run.trace, run.trace_devices, run.trace_lo,
+                                  run.trace_hi, table))
+
+
 def scope_us_per_token(run, pick) -> Optional[float]:
     """Chip-microseconds of own device time under the program scopes that
     ``pick(scopes)`` names, per token of the traced window; None where
     the program names no scopes or the run has no trace."""
-    names = program_scopes()
-    if (names is None or run.trace is None or not run.trace_devices
-            or not run.tokens):
+    own = run_scope_seconds(run) if run.tokens else None
+    if own is None:
         return None
-    table = cell_scope_table(run.cell)
-    if not table:
-        return None
-    own = by_scope(scope_seconds(run.trace, run.trace_devices, run.trace_lo,
-                                 run.trace_hi, table))
-    seconds = sum(own.get(s, 0.0) for s in pick(names))
+    seconds = sum(own.get(s, 0.0) for s in pick(program_scopes()))
     if seconds <= 0:
         return None
     return 1e6 * seconds * len(run.trace_devices) / run.tokens
@@ -447,7 +464,7 @@ def dispatch_ms_mean(spans: Sequence[Span], lo: float, hi: float
 # -- the script -------------------------------------------------------------------
 
 def report(trace: trace_reduce.Trace, spans: Sequence[Span],
-           devices: Sequence[int], cell=None) -> dict:
+           devices: Sequence[int], cell=None, plan=None) -> dict:
     """What ``main`` prints for one kept trace."""
     lo, hi = trace_reduce.window_bounds(trace)
     busy = trace_reduce.device_busy_s(trace, devices, lo, hi)
@@ -466,7 +483,7 @@ def report(trace: trace_reduce.Trace, spans: Sequence[Span],
             idle_share_out=d["idle_out_s"] / outside if outside > 0
             else None)
     if cell is not None:
-        table = cell_scope_table(cell)
+        table = cell_scope_table(cell, plan)
         own = scope_seconds(trace, devices, lo, hi, table)
         scoped = by_scope(own)
         total = sum(scoped.values())
@@ -479,20 +496,32 @@ def report(trace: trace_reduce.Trace, spans: Sequence[Span],
 
 
 def main(argv=None) -> int:
-    from bench import cells
+    from bench import cells, harness
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace", help="a trace directory or .xplane.pb file")
     ap.add_argument("--workload", default=None,
                     help="the cell whose programs name the device's ops")
+    ap.add_argument("--seed", type=int, default=None,
+                    help="the run's seed, for a cell whose exchange runs "
+                    "the daemon's plan")
     args = ap.parse_args(argv)
     trace = trace_reduce.load(args.trace)
     cell = cells.load_cell(args.workload) if args.workload else None
+    plan = None
+    if cell is not None and cell.a2a_impl == "plan":
+        if args.seed is None:
+            ap.error(f"{cell.name} runs the daemon's plan: give --seed")
+        plan = harness.daemon_plan(
+            cells.load_arch(cell.config["arch"]), cell.config["model"],
+            cell.plan_cluster, cell.traffic["batch"],
+            cell.traffic["prompt_len"], args.seed)
     devices = sorted(trace.device_ops)[:cell.chips if cell else None]
     if not devices:
         print("no device ops in the trace", file=sys.stderr)
         return 1
-    print(json.dumps(report(trace, load_spans(args.trace), devices, cell)))
+    print(json.dumps(report(trace, load_spans(args.trace), devices, cell,
+                            plan)))
     return 0
 
 

@@ -32,7 +32,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .. import weights
 from ..seeds import seed_seq
 from . import model, plan_check
 
@@ -64,8 +63,8 @@ def sample(cell, seed: int, completed: Sequence[tuple]
     return [completed[i] for i in pick], rows
 
 
-def _jobs(dims: dict, batches: List[tuple], rows: np.ndarray,
-          n_shards: int, devices, globals_: list) -> List[dict]:
+def _jobs(batches: List[tuple], rows: np.ndarray, n_shards: int, devices,
+          globals_: list) -> List[dict]:
     """One forward per device over its share of ``batches``."""
     jobs = []
     for i, dev in enumerate(devices):
@@ -85,25 +84,25 @@ def _jobs(dims: dict, batches: List[tuple], rows: np.ndarray,
     return jobs
 
 
-def reference_gaps(dims: dict, seed: int, batches: List[tuple],
+def reference_gaps(arch, dims: dict, seed: int, batches: List[tuple],
                    rows: np.ndarray, n_shards: int, devices,
                    lows: Sequence[str] = ()) -> Dict:
     """Served-token gaps of ``batches`` at ``rows``, and for each
     precision in ``lows`` the gaps of the tokens that the reference
     computed in it puts first.  The batches go through in passes of at
     most ``TOKENS_PER_PASS`` tokens a device, so that any sample fits."""
-    maker = weights.LayerMaker(dims)
     devices = list(devices)[:max(1, len(batches))]
-    globals_ = [weights.make_globals(dims, seed, device=d) for d in devices]
-    ref = model.Reference(dims, maker)
-    lowered = {low: model.Reference(dims, maker, low=low) for low in lows}
+    ref = model.Reference(arch, dims)
+    globals_ = [ref.globals(seed, device=d) for d in devices]
+    lowered = {low: model.Reference(arch, dims, low=low, makers=ref.makers)
+               for low in lows}
     (rows_in, p), n_gen = batches[0][0].shape, batches[0][1].shape[1]
     per_pass = max(1, TOKENS_PER_PASS // (rows_in * (p + n_gen - 1)))
     step = per_pass * len(devices)
     out = {"served_gap": [], "dropped": 0, **{low: [] for low in lows}}
     for start in range(0, len(batches), step):
-        jobs = _jobs(dims, batches[start:start + step], rows, n_shards,
-                     devices, globals_)
+        jobs = _jobs(batches[start:start + step], rows, n_shards, devices,
+                     globals_)
         firsts = {low: [_score(job, h, [], low)[1] for job, (h, _) in
                         zip(jobs, r.hidden(seed, jobs))]
                   for low, r in lowered.items()}
@@ -127,8 +126,7 @@ def _score(job: dict, hidden, extra: List[np.ndarray], low: Optional[str]):
     pos = model.served_positions(job["prompt_len"], served.shape[1])
     cand = np.stack([served[rows]] + list(extra), axis=-1)
     h = hidden[rows][:, pos]
-    g = job["globals"]
-    best, arg, at = model.score(g["final_norm"]["scale"], g["lm_head"], h,
+    best, arg, at = model.score(*model.head(job["globals"]), h,
                                 jax.device_put(cand.astype(np.int32),
                                                job["device"]), low=low)
     return model.gaps(best, at), np.asarray(arg)
@@ -151,7 +149,8 @@ def passes(checks: Dict) -> bool:
         for c in checks.values())
 
 
-def check_outputs(cell, dims: dict, seed: int, completed: Sequence[tuple],
+def check_outputs(cell, arch, dims: dict, seed: int,
+                  completed: Sequence[tuple],
                   devices, answers: Optional[List[tuple]], unanswered: int,
                   m_gpus: int, lows: Sequence[str] = ()):
     """({name: {"value", "limit"}}, info) for one run.  With
@@ -164,8 +163,8 @@ def check_outputs(cell, dims: dict, seed: int, completed: Sequence[tuple],
     if completed:
         t0 = time.perf_counter()
         batches, rows = sample(cell, seed, completed)
-        got = reference_gaps(dims, seed, batches, rows, _n_shards(cell),
-                             devices, lows)
+        got = reference_gaps(arch, dims, seed, batches, rows,
+                             _n_shards(cell), devices, lows)
         stats = gap_stats(got["served_gap"])
         values.update({k: stats[k] for k in gap_names})
         info.update({"compared_tokens": int(got["served_gap"].size),

@@ -9,9 +9,14 @@ reference's best: the comparison then shows semantics and not rounding.
 In bfloat16 a served token's gap at this size is set by which rows a
 rounding-flipped route or capacity drop happens to touch (0 to 0.11 over
 seeds and sampled batches), which no fixed limit separates from the
-e4m3 control's (0.014 to 0.37)."""
+e4m3 control's (0.014 to 0.37).
+
+``run_batches`` ends a window after a fixed number of finished batches;
+``named_kernels`` gives the recorded kernel trace the names the program's
+kernels carry."""
 
 from bench import cells
+from bench import trace_reduce as tr
 
 MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
          "head_dim": 16, "d_ff": 128, "vocab": 256, "num_experts": 4,
@@ -36,8 +41,8 @@ LIMITS = {"logit_gap_max": 1e-3, "plan_bytes_err": 1e-9,
 
 def cell(kind: str, mesh: bool = False) -> cells.Cell:
     conf = {"name": "smoke", "registry": "megatron-moe-32e", "smoke": True,
-            "model": dict(MODEL),
-            "program": {"scan_layers": True, "capacity_factor": 1.0}}
+            "arch": "moe_gqa", "model": dict(MODEL),
+            "program": {"scan_layers": True, "moe.capacity_factor": 1.0}}
     if kind == "prefill":
         traffic = {"name": "smoke_prefill", "batch": 4, "prompt_len": 32,
                    "gen_len": 1, "prompt_tokens": {"s": 1.0},
@@ -60,3 +65,32 @@ def cell(kind: str, mesh: bool = False) -> cells.Cell:
         a2a_impl="plan" if mesh else None,
         plan_cluster=[2, 2] if mesh else None, correct=correct,
         end_to_end=e2e, per_layer=layer)
+
+
+def run_batches(n: int):
+    """A ``RequestPool.run_window`` that ends the window after ``n``
+    finished batches, however long they take: what a test compares then
+    does not hang on the host's load."""
+    def run_window(pool, prompts, t_end):
+        for _ in range(n):
+            batch = prompts.batch(pool.batch, pool.prompt_len)
+            _, served = pool._batch(batch, float("inf"), count=True)
+            pool.attempted += pool.batch
+            pool.completed.append((batch, served))
+            pool.run.batches += 1
+    return run_window
+
+
+def named_kernels(trace):
+    """The recorded kernel trace with its kernels named as the program
+    names them since: it was recorded before they had names (pack and
+    unpack in turn, three times)."""
+    ops, k = [], 0
+    for e in trace.device_ops[0]:
+        if tr.opcode(e.name) == "custom-call":
+            kind = ("a2a_pack", "a2a_unpack")[k % 2]
+            k += 1
+            e = tr.Event(e.name.replace("_unknown_", kind, 1), e.start_ns,
+                         e.dur_ns)
+        ops.append(e)
+    return tr.Trace(device_ops={0: ops}, host_spans=trace.host_spans)

@@ -17,6 +17,7 @@ from bench.tests import smoke
 from repro.comm import all_to_all
 
 seed = int(sys.argv[1])
+harness.RequestPool.run_window = smoke.run_batches(4)
 cell = smoke.cell("prefill", mesh=True)
 sound = harness.run(cell, seed, 1.5, False, jax.devices())
 all_to_all.ALL_TO_ALL_IMPLS["plan"] = lambda x, slow_axis, fast_axes, **k: x

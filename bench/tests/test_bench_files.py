@@ -61,7 +61,9 @@ def test_every_cell_loads_and_names_existing_entries(cell, bench):
         assert os.path.exists(cells.reader_path(
             m["name"], os.path.join(cells.BENCH_DIR, "end_to_end")))
     limits = c.correct["limits"]
-    assert "off_best_share" in limits
+    assert limits and set(limits) <= {"logit_gap_max", "logit_gap_mean",
+                                      "off_best_share", "plan_bytes_err",
+                                      "plan_unanswered"}
     assert all(v is not None for v in limits.values())
 
 
@@ -88,7 +90,8 @@ def test_weight_layout_matches_the_programs_init(name):
     cfg = get_config(conf["registry"], n_layers=m["n_layers"],
                      param_dtype=m["param_dtype"])
     shapes = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
-    assert weights.check_layout(shapes, m) is None
+    tree = cells.load_arch(conf["arch"]).param_tree(m)
+    assert weights.check_layout(shapes, tree) is None
 
 
 def test_a_reader_dropped_into_metrics_is_found(tmp_path):
@@ -172,15 +175,16 @@ def test_one_layer_regenerates_exactly():
     dims = dict(cells.load_config("megatron-moe-32e-2l")["model"],
                 d_model=32, n_heads=4, n_kv_heads=2, head_dim=8, d_ff=16,
                 vocab=64, num_experts=4, n_layers=3)
+    tree = cells.load_arch("moe_gqa").param_tree(dims)
     seed = 2 ** 33 + 5
-    full = weights.make_params(dims, seed)
-    maker = weights.LayerMaker(dims)
+    full = weights.make_params(tree, seed)
+    maker = weights.LayerMaker(tree, "blocks")
     for layer in range(3):
         one = maker(seed, layer)
         for a, b in zip(jax.tree.leaves(full["blocks"]),
                         jax.tree.leaves(one)):
             assert jnp.array_equal(a[layer], b)
-    other = weights.make_params(dims, seed + 1)
+    other = weights.make_params(tree, seed + 1)
     assert not jnp.array_equal(full["embed"], other["embed"])
 
 

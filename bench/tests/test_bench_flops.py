@@ -4,6 +4,8 @@ import pytest
 
 from bench import cells, flops
 
+moe_gqa = cells.load_arch("moe_gqa")
+
 
 def megatron(layers=2):
     return dict(cells.load_config("megatron-moe-32e-2l")["model"],
@@ -24,14 +26,14 @@ def test_megatron_prefill_batch_by_hand():
     per_seq = 2048 * (proj + router + expert) + scores
     head = 2 * 2048 * 50304                   # last position only
     want = 4 * (2 * per_seq + head)
-    assert flops.prefill_flops(megatron(), 4, 2048) == want
+    assert moe_gqa.prefill_flops(megatron(), 4, 2048) == want
     assert want == pytest.approx(3.7826e12, rel=1e-4)
 
 
 def test_megatron_12_layers_on_four_chips_by_hand():
-    one = flops.prefill_flops(megatron(1), 8, 2048) - 8 * 2 * 2048 * 50304
+    one = moe_gqa.prefill_flops(megatron(1), 8, 2048) - 8 * 2 * 2048 * 50304
     head = 8 * 2 * 2048 * 50304
-    assert flops.prefill_flops(megatron(12), 8, 2048) == 12 * one + head
+    assert moe_gqa.prefill_flops(megatron(12), 8, 2048) == 12 * one + head
 
 
 def test_mixtral_decode_step_by_hand():
@@ -42,19 +44,19 @@ def test_mixtral_decode_step_by_hand():
     scores = 4 * 32 * 128 * 385               # position 384 sees 385 keys
     head = 2 * 4096 * 32000
     want = 64 * (2 * (proj + router + expert + scores) + head)
-    assert flops.decode_step_flops(mixtral(), 64, 384) == want
+    assert moe_gqa.decode_step_flops(mixtral(), 64, 384) == want
 
 
 def test_mixtral_window_never_binds_below_4096():
     m = mixtral()
     assert m["swa_window"] == 4096
     full = dict(m, swa_window=None)
-    assert flops.prefill_flops(m, 64, 128) == flops.prefill_flops(full, 64,
+    assert moe_gqa.prefill_flops(m, 64, 128) == moe_gqa.prefill_flops(full, 64,
                                                                   128)
-    assert flops.decode_step_flops(m, 64, 639) == \
-        flops.decode_step_flops(full, 64, 639)
-    assert flops.decode_step_flops(m, 1, 5000) < \
-        flops.decode_step_flops(full, 1, 5000)
+    assert moe_gqa.decode_step_flops(m, 64, 639) == \
+        moe_gqa.decode_step_flops(full, 64, 639)
+    assert moe_gqa.decode_step_flops(m, 1, 5000) < \
+        moe_gqa.decode_step_flops(full, 1, 5000)
 
 
 def test_a2a_kernel_bytes_by_hand():

@@ -40,7 +40,7 @@ def read(name, run):
 def _run(trace, cell="hand-made", **kw):
     lo, hi = tr.window_bounds(trace)
     base = dict(trace=trace, trace_devices=[0], trace_lo=lo, trace_hi=hi,
-                tokens=0, cell=types.SimpleNamespace(name=cell))
+                tokens=0, cell=types.SimpleNamespace(name=cell), plan=None)
     base.update(kw)
     return types.SimpleNamespace(**base)
 
@@ -190,7 +190,6 @@ def test_the_smoke_cells_programs_name_their_ops(kind, monkeypatch):
     assert {scopes.MOE_ROUTE, scopes.MOE_DISPATCH, scopes.MOE_EXPERT_FFN,
             scopes.MOE_COMBINE, scopes.ATTENTION_KV_CACHE} <= found
     assert pt.cell_scope_table(cell) is table        # made once
-    assert pt.cell_programs(smoke.cell(kind, mesh=True)) == []
 
 
 # PREFILL as a tree without scopes compiled it: no op_name of ours, and
@@ -248,7 +247,8 @@ def test_the_reader_lowers_the_programs_the_harness_runs():
     prefill = serve.make_prefill_step(
         cfg, None, cache_len=s + traffic["gen_len"])
     step = serve.make_serve_step(cfg, None)
-    params = weights.make_params(dims, 5, device=dev)
+    tree = cells.load_arch(cell.config["arch"]).param_tree(dims)
+    params = weights.make_params(tree, 5, device=dev)
     batch = {"tokens": jax.device_put(np.zeros((b, s), np.int32), dev)}
     logits, cache = prefill(params, batch)
     toks = jnp.argmax(logits, -1)
@@ -261,7 +261,7 @@ def test_a_cache_of_unscoped_programs_is_compiled_past(monkeypatch):
     flag = "jax_compilation_cache_include_metadata_in_key"
     calls = []
 
-    def programs(cell):
+    def programs(cell, plan):
         calls.append(getattr(jax.config, flag))
         return [RAN] if len(calls) == 1 else [PREFILL]
 
@@ -369,7 +369,8 @@ def test_recorded_traces_read_as_before(name, capsys):
         want["top"][1], rel=1e-12)]
     assert bd["idle_gaps"] == [[k, pytest.approx(v, rel=1e-12)]
                                for k, v in want["idle_gaps"]]
-    run = _run(t, trace_lo=lo, trace_hi=hi, batches=3,
+    # the kernels recorded before they had names, named as they are since
+    run = _run(smoke.named_kernels(t), trace_lo=lo, trace_hi=hi, batches=3,
                peaks=peaks.peaks_for("TPU v5 lite"))
     for reader in ("device.idle_share", "a2a_pack_roofline"):
         got = read(reader, run)
@@ -407,3 +408,55 @@ def test_a_traced_run_keeps_the_program_spans_on_their_threads(tmp_path):
     assert window not in {s.thread for s in synth}
     assert {s.stats["kind"] for s in synth} <= {
         "cold", "warm", "upgrade", "prewarm", "rerepair"}
+
+
+MESH_SCRIPT = r"""
+import json
+import jax
+import numpy as np
+from bench import cells, harness, program_trace as pt, weights
+from bench.tests import smoke
+from repro import scopes
+
+cell = smoke.cell("prefill", mesh=True)
+arch, dims = cells.load_arch(cell.config["arch"]), cell.config["model"]
+plan = harness.daemon_plan(arch, dims, cell.plan_cluster,
+                           cell.traffic["batch"], cell.traffic["prompt_len"],
+                           3)
+cfg = harness.model_config(cell)
+tree = arch.param_tree(dims)
+mesh, param_sh, tok_sh = harness.placement(cell, cfg, tree, jax.devices()[0])
+params = weights.make_params(tree, 3, out_shardings=param_sh)
+tokens = jax.device_put(np.zeros((cell.traffic["batch"],
+                                  cell.traffic["prompt_len"]), np.int32),
+                        tok_sh)
+prefill, _ = harness.served_steps(cell, cfg, mesh, plan)
+ran = prefill.lower(params, {"tokens": tokens}).as_text()
+lowered = pt.cell_lowered(cell, plan)
+table = pt.cell_scope_table(cell, plan)
+print(json.dumps({"same": [lo.as_text() for lo in lowered] == [ran],
+                  "scopes": sorted({s for s in table.values() if s})}))
+"""
+
+
+def test_the_mesh_cells_programs_lower_as_run_and_name_the_exchange():
+    """On four (virtual CPU) devices: the expert-parallel prefill is
+    lowered for the readers exactly as the harness runs it, sharded over
+    the cell's mesh with the run's plan, and its compiled ops fall under
+    the exchange's scopes."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [cells.ROOT, os.path.join(cells.ROOT, "src")]))
+    proc = subprocess.run([sys.executable, "-c", MESH_SCRIPT],
+                          cwd=cells.ROOT, env=env, capture_output=True,
+                          text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["same"]
+    assert {scopes.EXCHANGE_PACK, scopes.EXCHANGE_STAGE,
+            scopes.EXCHANGE_UNPACK, scopes.MOE_EXPERT_FFN} \
+        <= set(out["scopes"])

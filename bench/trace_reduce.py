@@ -215,15 +215,13 @@ def device_busy_s(trace: Trace, devices: Sequence[int], lo: float,
 
 
 def op_self_seconds(trace: Trace, devices: Sequence[int], lo: float,
-                    hi: float, select=None) -> Dict[str, float]:
-    """Own seconds per op (``short_name``), averaged over ``devices``;
-    ``select(event_name)`` keeps a subset."""
+                    hi: float) -> Dict[str, float]:
+    """Own seconds per op (``short_name``), averaged over ``devices``."""
     total: Dict[str, float] = defaultdict(float)
     for d in devices:
         for ev, own in self_times(clip(trace.device_ops.get(d, ()), lo,
                                        hi)):
-            if select is None or select(ev.name):
-                total[short_name(ev.name)] += own / 1e9 / len(devices)
+            total[short_name(ev.name)] += own / 1e9 / len(devices)
     return dict(total)
 
 
@@ -256,17 +254,6 @@ def breakdown(trace: Trace, devices: Sequence[int], lo: float, hi: float,
         return {"device_ops": [], "idle_gaps": []}
     return {"device_ops": top(op_self_seconds(trace, devices, lo, hi), n),
             "idle_gaps": top(labelled_idle(trace, devices[0], lo, hi), n)}
-
-
-def is_collective(name: str) -> bool:
-    op, ins = opcode(name), instruction(name)
-    return any(c in op or c in ins
-               for c in ("collective-permute", "all-to-all"))
-
-
-def is_tpu_kernel(name: str) -> bool:
-    return ('custom_call_target="tpu_custom_call"' in name
-            and opcode(name) == "custom-call")
 
 
 WINDOW_SPAN = "bench.window"

@@ -2,59 +2,60 @@
 
 The benchmark, not the program, makes the weights, so that the plain
 reference can make the very same numbers again from the seed alone.  The
-layout is the served model's parameter tree (scan-stacked blocks); the
-harness checks it against the program's own ``init`` shapes before use.
+layout is the served model's parameter tree, as the configuration's
+architecture module (``bench/arch``) declares it: a tree of ``Leaf``s,
+each with its shape, its dtype, how many of its leading axes are stacked
+(layer, then expert) and how it is initialised.  The harness checks the
+layout against the program's own ``init`` shapes before use.
 
 Every leaf draws from a key folded from the root key, the leaf's path and
 its stacked indices (layer, then expert), so one layer, or one expert of
-one layer, can be regenerated on its own.  Values follow the usual
-initialisation: projections N(0, 1/fan_in), embeddings and LM head
-N(0, 0.02^2), norm scales one; each drawn in float32 and stored in the
-leaf's dtype.
+one layer, can be regenerated on its own.  Each leaf is drawn in float32
+and stored in its dtype.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import zlib
-from typing import Dict, Optional
+from typing import Dict, Iterable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+@dataclasses.dataclass(frozen=True)
+class Leaf:
+    """One parameter leaf as an architecture module declares it.
+
+    ``stacked`` leading axes index the layer (and then the expert) that a
+    slice belongs to; each index folds into the slice's key.  ``init`` is
+    ``"fan_in"`` (N(0, 1/fan_in), fan-in the second-last axis), ``"embed"``
+    (N(0, 0.02^2)) or ``"ones"``."""
+
+    shape: Tuple[int, ...]
+    dtype: str
+    stacked: int = 0
+    init: str = "fan_in"
 
 
-def block_shapes(dims: dict, stacked: bool = True) -> dict:
-    """Shapes of one MoE block's leaves (with a leading layer axis when
-    ``stacked``)."""
-    d, h, kv = dims["d_model"], dims["n_heads"], dims["n_kv_heads"]
-    dh = dims.get("head_dim") or d // h
-    f, e = dims["d_ff"], dims["num_experts"]
-    pdt = dims["param_dtype"]
-    lead = (dims["n_layers"],) if stacked else ()
-    return {
-        "norm1": {"scale": _sds(lead + (d,), "float32")},
-        "attn": {"wq": _sds(lead + (d, h * dh), pdt),
-                 "wk": _sds(lead + (d, kv * dh), pdt),
-                 "wv": _sds(lead + (d, kv * dh), pdt),
-                 "wo": _sds(lead + (h * dh, d), pdt)},
-        "norm2": {"scale": _sds(lead + (d,), "float32")},
-        "moe": {"router": _sds(lead + (d, e), "float32"),
-                "w_gate": _sds(lead + (e, d, f), pdt),
-                "w_up": _sds(lead + (e, d, f), pdt),
-                "w_down": _sds(lead + (e, f, d), pdt)},
-    }
+def stack(tree, n: int):
+    """``tree``'s leaves with a leading axis of ``n`` more stacked
+    slices (the layers of a scanned stack, the experts of a layer)."""
+    return jax.tree.map(lambda s: dataclasses.replace(
+        s, shape=(n,) + tuple(s.shape), stacked=s.stacked + 1), tree,
+        is_leaf=_is_leaf)
 
 
-def param_shapes(dims: dict) -> dict:
-    d, v, pdt = dims["d_model"], dims["vocab"], dims["param_dtype"]
-    return {"embed": _sds((v, d), pdt),
-            "final_norm": {"scale": _sds((d,), "float32")},
-            "lm_head": _sds((d, v), pdt),
-            "blocks": block_shapes(dims)}
+def _is_leaf(x) -> bool:
+    return isinstance(x, Leaf)
+
+
+def shapes(tree):
+    """The tree as ``ShapeDtypeStruct``s."""
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        tuple(s.shape), jnp.dtype(s.dtype)), tree, is_leaf=_is_leaf)
 
 
 def _path(path) -> str:
@@ -62,34 +63,29 @@ def _path(path) -> str:
                     for p in path)
 
 
-def _n_stacked(path: str) -> int:
-    """Stacked leading axes of a leaf: layer, and expert for expert FFNs."""
-    if not path.startswith("blocks/"):
-        return 0
-    return 2 if path.split("/")[-1] in ("w_gate", "w_up", "w_down") else 1
-
-
-def _draw(key, path: str, shape, dtype):
-    name = path.split("/")[-1]
-    if name == "scale":
-        return jnp.ones(shape, dtype)
+def _draw(key, leaf: Leaf, shape):
+    if leaf.init == "ones":
+        return jnp.ones(shape, leaf.dtype)
     x = jax.random.normal(key, shape, jnp.float32)
-    if name in ("embed", "lm_head"):
-        return (x * 0.02).astype(dtype)
-    return (x * float(1.0 / np.sqrt(shape[-2]))).astype(dtype)
+    if leaf.init == "embed":
+        return (x * 0.02).astype(leaf.dtype)
+    if leaf.init == "fan_in":
+        return (x * float(1.0 / np.sqrt(shape[-2]))).astype(leaf.dtype)
+    raise ValueError(f"unknown init {leaf.init!r}")
 
 
-def _leaf(root, path: str, sds, fixed=()):
-    """One leaf; ``fixed`` pins its first stacked indices (layer, ...)."""
+def _leaf(root, path: str, leaf: Leaf, fixed=()):
+    """One leaf; ``fixed`` pins its first stacked indices (layer, ...) and
+    ``leaf.shape`` then holds only the axes after them."""
     key = jax.random.fold_in(root, zlib.crc32(path.encode()))
     for i in fixed:
         key = jax.random.fold_in(key, i)
-    n_free = _n_stacked(path) - len(fixed)
-    shape = tuple(sds.shape)
+    n_free = leaf.stacked - len(fixed)
+    shape = tuple(leaf.shape)
     inner = shape[n_free:]
 
     def draw(k):
-        return _draw(k, path, inner, sds.dtype)
+        return _draw(k, leaf, inner)
 
     for axis in reversed(range(n_free)):
         draw = (lambda f, n: lambda k: jax.vmap(
@@ -104,13 +100,11 @@ def root_key(seed: int):
     return jax.random.key(int(state[0]) & 0x7FFFFFFF)
 
 
-def make_params(dims: dict, seed: int, out_shardings=None, device=None):
+def make_params(tree, seed: int, out_shardings=None, device=None):
     """The whole parameter tree, in one jitted call on the device."""
-    shapes = param_shapes(dims)
-
     def build(root):
         return jax.tree_util.tree_map_with_path(
-            lambda p, s: _leaf(root, _path(p), s), shapes)
+            lambda p, s: _leaf(root, _path(p), s), tree, is_leaf=_is_leaf)
 
     key = root_key(seed)
     if device is not None:
@@ -118,17 +112,27 @@ def make_params(dims: dict, seed: int, out_shardings=None, device=None):
     return jax.jit(build, out_shardings=out_shardings)(key)
 
 
-class LayerMaker:
-    """Regenerates one block's leaves by layer index, with one compiled
-    program for every layer (the index is an argument, not a constant)."""
+def make_globals(tree, stacks: Iterable[str], seed: int,
+                 device=None) -> Dict:
+    """The leaves outside the layer stacks (embedding, final norm, head)."""
+    stacks = set(stacks)
+    return make_params({k: v for k, v in tree.items() if k not in stacks},
+                       seed, device=device)
 
-    def __init__(self, dims: dict):
-        shapes = block_shapes(dims, stacked=False)
+
+class LayerMaker:
+    """Regenerates one layer of the stack ``tree[name]`` by its index,
+    with one compiled program for every layer (the index is an argument,
+    not a constant)."""
+
+    def __init__(self, tree, name: str):
+        one = jax.tree.map(lambda s: dataclasses.replace(
+            s, shape=tuple(s.shape)[1:]), tree[name], is_leaf=_is_leaf)
 
         def build(root, layer):
             return jax.tree_util.tree_map_with_path(
-                lambda p, s: _leaf(root, "blocks/" + _path(p), s,
-                                   fixed=(layer,)), shapes)
+                lambda p, s: _leaf(root, f"{name}/" + _path(p), s,
+                                   fixed=(layer,)), one, is_leaf=_is_leaf)
 
         self._build = jax.jit(build)
 
@@ -139,28 +143,13 @@ class LayerMaker:
         return self._build(key, idx)
 
 
-def make_globals(dims: dict, seed: int, device=None) -> Dict:
-    """The leaves outside the blocks: embed, final norm, LM head."""
-    shapes = {k: v for k, v in param_shapes(dims).items() if k != "blocks"}
-
-    def build(root):
-        return jax.tree_util.tree_map_with_path(
-            lambda p, s: _leaf(root, _path(p), s), shapes)
-
-    key = root_key(seed)
-    if device is not None:
-        key = jax.device_put(key, device)
-    return jax.jit(build)(key)
-
-
-def check_layout(program_shapes, dims: dict) -> Optional[str]:
+def check_layout(program_shapes, tree) -> Optional[str]:
     """None when the program's parameter tree matches this layout, else
     what differs (the harness refuses to run then)."""
-    ours = param_shapes(dims)
     a = {_path(p): (tuple(s.shape), jnp.dtype(s.dtype)) for p, s in
          jax.tree_util.tree_flatten_with_path(program_shapes)[0]}
     b = {_path(p): (tuple(s.shape), jnp.dtype(s.dtype)) for p, s in
-         jax.tree_util.tree_flatten_with_path(ours)[0]}
+         jax.tree_util.tree_flatten_with_path(shapes(tree))[0]}
     if a == b:
         return None
     diff = sorted(set(a.items()) ^ set(b.items()))
